@@ -16,8 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.dist.compat import axis_size, shard_map
 
 
 def batch_norm(x, scale, bias, *, eps: float = 1e-5):

@@ -17,8 +17,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.dist.compat import shard_map
 
 
 def run_partitioned(branches: Sequence[Callable], *, mesh: Mesh,

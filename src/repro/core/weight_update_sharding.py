@@ -40,8 +40,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.dist.compat import shard_map
 
 from repro.core.gradient_summation import flatten_tree, unflatten_tree
 from repro.optim.base import Optimizer

@@ -9,9 +9,7 @@ The one place sharding policy lives:
     to mesh axes per sharding mode, with divisibility fallback and the C1
     weight-update-sharding param/optimizer split (``repro.dist.sharding``);
   * ``use_rules`` / ``constrain`` — mesh-context-scoped activation
-    constraints, no-ops outside a scope (``repro.dist.context``);
-  * ``repro.dist.compat`` — JAX version shims (``shard_map``,
-    ``make_mesh``, ``AxisType``).
+    constraints, no-ops outside a scope (``repro.dist.context``).
 """
 from repro.dist.context import constrain, current_rules, use_rules
 from repro.dist.rules import ACTIVATION_AXES, MODES, PARAM_AXES, build_table

@@ -18,8 +18,11 @@ used by kernel integration tests).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import dispatch as _dispatch
 from repro.kernels import quant as _quant
@@ -27,6 +30,39 @@ from repro.kernels import ref as _ref
 
 # Back-compat alias (pre-registry callers peeked at the env directly).
 _pallas_mode = _dispatch.pallas_mode
+
+
+# --------------------------------------------------------------------------- #
+# Running a kernel under a mesh.
+# --------------------------------------------------------------------------- #
+_Q_AXES = ("batch", None, "act_heads", None)  # (B, S, H, D) queries / KV
+
+
+def _per_shard(fn, args, names):
+    """Call the Pallas kernel ``fn(*args)`` on each device's shard.
+
+    XLA cannot partition a Mosaic kernel, so under a ``use_rules`` scope
+    whose mesh has more than one device the call runs inside
+    ``shard_map``: batch on the rules' batch axes, heads on the model
+    axes (``names`` gives each argument's logical axes; the output is
+    laid out like ``args[0]``). A query head's KV head is ``h // G`` on
+    every shard only if query and KV heads split alike, so heads stay
+    whole when they do not (e.g. fewer KV heads than model shards).
+    """
+    from repro.dist import current_rules
+
+    rules = current_rules()
+    mesh = getattr(rules, "mesh", None)
+    if getattr(mesh, "devices", None) is None or mesh.devices.size == 1:
+        return fn(*args)
+    specs = [rules.spec_for(n, jnp.shape(a)) for n, a in zip(names, args)]
+    heads = {s[n.index("act_heads")] for s, n in zip(specs, names)
+             if "act_heads" in n}
+    if len(heads) > 1:
+        specs = [P(*(None if a == "act_heads" else e for a, e in zip(n, s)))
+                 for s, n in zip(specs, names)]
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=specs[0], check_vma=False)(*args)
 
 
 # --------------------------------------------------------------------------- #
@@ -44,10 +80,10 @@ def attention(q, k, v, *, causal=True, window=None, q_offset=0, k_offset=0,
             q, k, v, causal=causal, window=window, q_offset=q_offset,
             k_offset=k_offset, scale=scale, chunk=chunk,
         )
-    return impl(
-        q, k, v, causal=causal, window=window, q_offset=q_offset,
-        k_offset=k_offset, scale=scale, interpret=interpret,
-    )
+    fn = functools.partial(
+        impl, causal=causal, window=window, q_offset=q_offset,
+        k_offset=k_offset, scale=scale, interpret=interpret)
+    return _per_shard(fn, (q, k, v), (_Q_AXES,) * 3)
 
 
 def _chunked_attention(q, k, v, *, causal, window, q_offset, k_offset, scale,
@@ -195,13 +231,14 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
 
     q: (B, C, H, D) — C tokens per row this step (decode rows feed 1,
     chunked-prefill rows up to C; ``n_valid`` masks the rest).
-    kp/vp: (P, page, K, hd) physical page pool — bf16, int8 (hd == D) or
-    int4-packed (hd == D // 2); the new tokens' K/V are already
+    kp/vp: (P, K, page, hd) physical page pool, head-major within a
+    page — bf16, int8 (hd == D) or int4-packed (hd == D // 2); the new
+    tokens' K/V are already
     scattered into their pages (``layers.paged_cache_insert`` runs
     before attention).
     page_table: (B, max_pages) int32 physical page ids (-1 unmapped).
     pos: (B,) absolute position of each row's first token this step.
-    kp_scale/vp_scale: (P, page, K) dequant scales for quantized pools;
+    kp_scale/vp_scale: (P, K, page) dequant scales for quantized pools;
     both the Pallas kernel (dequant-in-kernel, fp32 accumulation) and
     the jnp fallback consume them.
 
@@ -219,15 +256,26 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
         return impl(q, kp, vp, page_table, pos=pos, n_valid=n_valid,
                     window=window, scale=scale, kp_scale=kp_scale,
                     vp_scale=vp_scale)
-    return impl(q, kp, vp, page_table, pos=pos, n_valid=n_valid,
-                window=window, scale=scale, kp_scale=kp_scale,
-                vp_scale=vp_scale, interpret=interpret)
+
+    def fn(q, kp, vp, page_table, pos, n_valid, *scales):
+        ks, vs = scales or (None, None)
+        return impl(q, kp, vp, page_table, pos=pos, n_valid=n_valid,
+                    window=window, scale=scale, kp_scale=ks, vp_scale=vs,
+                    interpret=interpret)
+
+    pool = (None, "act_heads", None, None)
+    args = (q, kp, vp, page_table, pos, n_valid)
+    names = (_Q_AXES, pool, pool, ("batch", None), ("batch",), ("batch",))
+    if kp_scale is not None:
+        args += (kp_scale, vp_scale)
+        names += (pool[:3], pool[:3])
+    return _per_shard(fn, args, names)
 
 
 def _paged_attention_jnp(q, kp, vp, page_table, *, pos, n_valid, window,
                          scale, kp_scale, vp_scale):
     B, C, H, D = q.shape
-    P, page, K, hd = kp.shape
+    P, K, page, hd = kp.shape
     G = H // K
     scale = scale if scale is not None else D ** -0.5
     npg = page_table.shape[1]
@@ -239,10 +287,10 @@ def _paged_attention_jnp(q, kp, vp, page_table, *, pos, n_valid, window,
         kf = _quant.dequantize(kp[safe], kp_scale[safe], D)
         vf = _quant.dequantize(vp[safe], vp_scale[safe], D)
     else:
-        kf = kp[safe].astype(jnp.float32)  # (B, npg, page, K, hd)
+        kf = kp[safe].astype(jnp.float32)  # (B, npg, K, page, hd)
         vf = vp[safe].astype(jnp.float32)
-    kf = kf.reshape(B, npg * page, K, D)
-    vf = vf.reshape(B, npg * page, K, D)
+    kf = jnp.swapaxes(kf, 2, 3).reshape(B, npg * page, K, D)
+    vf = jnp.swapaxes(vf, 2, 3).reshape(B, npg * page, K, D)
     qf = (q.astype(jnp.float32) * scale).reshape(B, C, K, G, D)
     logits = jnp.einsum("bckgd,blkd->bckgl", qf, kf)
     kpos = jnp.arange(npg * page, dtype=jnp.int32)
@@ -357,6 +405,9 @@ def mamba_step(h, u_t, dt_t, A, B_t, C_t, D):
 _dispatch.register(
     name="attention",
     jnp=_chunked_attention,
+    # Forward is the Pallas kernel; its custom VJP's backward is an XLA
+    # computation that recomputes the probabilities from the kernel's
+    # saved log-sum-exp, tile by tile — a stated choice, not a fallback.
     pallas="repro.kernels.flash_attention:flash_attention",
 )
 _dispatch.register(

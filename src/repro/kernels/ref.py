@@ -47,12 +47,12 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
     """Naive paged-decode attention oracle.
 
     q: (B, C, H, D) — C new tokens per row (decode: C=1 valid; chunked
-    prefill: up to C). kp/vp: (P, page, K, hd) physical page pool — the
+    prefill: up to C). kp/vp: (P, K, page, hd) physical page pool — the
     NEW tokens' K/V are assumed already written into their pages.
     page_table: (B, max_pages) int32 physical page ids, -1 unmapped.
     pos: (B,) absolute position of each row's first new token.
     n_valid: (B,) how many of the C tokens are real this step.
-    kp_scale/vp_scale: (P, page, K) per-row dequant scales for
+    kp_scale/vp_scale: (P, K, page) per-row dequant scales for
     quantized pools — int8 (hd == D) or int4-packed (hd == D // 2,
     see ``kernels/quant.py``).
 
@@ -64,7 +64,7 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
     from repro.kernels import quant
 
     B, C, H, D = q.shape
-    P, page, K, hd = kp.shape
+    P, K, page, hd = kp.shape
     G = H // K
     scale = scale if scale is not None else D ** -0.5
     npg = page_table.shape[1]
@@ -74,10 +74,10 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
         kg = quant.dequantize(kp[safe], kp_scale[safe], D)
         vg = quant.dequantize(vp[safe], vp_scale[safe], D)
     else:
-        kg = kp[safe].astype(jnp.float32)  # (B,npg,page,K,hd)
+        kg = kp[safe].astype(jnp.float32)  # (B,npg,K,page,hd)
         vg = vp[safe].astype(jnp.float32)
-    kg = kg.reshape(B, npg * page, K, D)
-    vg = vg.reshape(B, npg * page, K, D)
+    kg = jnp.swapaxes(kg, 2, 3).reshape(B, npg * page, K, D)
+    vg = jnp.swapaxes(vg, 2, 3).reshape(B, npg * page, K, D)
     qf = (q.astype(jnp.float32) * scale).reshape(B, C, K, G, D)
     logits = jnp.einsum("bckgd,blkd->bckgl", qf, kg)  # (B,C,K,G,L)
     kpos = jnp.arange(npg * page, dtype=jnp.int32)

@@ -7,9 +7,11 @@ initialization and only then builds the mesh.
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
+import math
 
-from repro.dist.compat import AxisType, make_mesh
+import jax
+from jax import make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -28,6 +30,20 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0) -> Mesh:
         )
     return make_mesh(
         (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
+
+
+def local_mesh() -> Mesh:
+    """Every device of this process as a (data, model) grid.
+
+    ``model`` is the largest divisor of the device count not above its
+    square root, ``data`` the rest: 2x2 on a four-chip host, 1x1 on one.
+    """
+    n = len(jax.devices())
+    model = max(m for m in range(1, math.isqrt(n) + 1) if n % m == 0)
+    return make_mesh(
+        (n // model, model), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2,
     )
 
 

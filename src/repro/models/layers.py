@@ -326,6 +326,10 @@ def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
     routes invalid token positions there so the scatter needs no
     conditional. Validity is carried by the page table (-1 = unmapped)
     plus per-row lengths, not by a per-slot ``slot_pos`` map.
+
+    Pages are head-major, ``(n_pages + 1, K, page, hd)`` (scales
+    ``(n_pages + 1, K, page)``), so one KV head's page is a
+    ``(page, hd)`` tile — the block the paged kernel DMAs.
     """
     K, hd = cfg.n_kv_heads, cfg.head_dim
     quantized = cfg.kv_cache_dtype in ("int8", "int4")
@@ -337,12 +341,12 @@ def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
         store_hd = hd // 2  # two nibbles per byte (kernels.quant layout)
     dt = jnp.int8 if quantized else _cdtype(cfg)
     cache = {
-        "kp": jnp.zeros((n_pages + 1, page, K, store_hd), dt),
-        "vp": jnp.zeros((n_pages + 1, page, K, store_hd), dt),
+        "kp": jnp.zeros((n_pages + 1, K, page, store_hd), dt),
+        "vp": jnp.zeros((n_pages + 1, K, page, store_hd), dt),
     }
     if quantized:
-        cache["kp_scale"] = jnp.zeros((n_pages + 1, page, K), jnp.float32)
-        cache["vp_scale"] = jnp.zeros((n_pages + 1, page, K), jnp.float32)
+        cache["kp_scale"] = jnp.zeros((n_pages + 1, K, page), jnp.float32)
+        cache["vp_scale"] = jnp.zeros((n_pages + 1, K, page), jnp.float32)
     return cache
 
 
@@ -358,7 +362,7 @@ def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid):
     the trash page. The engine guarantees every valid position's page is
     mapped before the step runs.
     """
-    P1, page = cache["kp"].shape[:2]
+    P1, _, page = cache["kp"].shape[:3]
     B, C, K, hd = k_new.shape
     npg = page_table.shape[1]
     logical = (jnp.asarray(pos, jnp.int32).reshape(B, 1)
@@ -369,14 +373,12 @@ def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid):
     ok = (jnp.arange(C, dtype=jnp.int32)[None, :]
           < jnp.asarray(n_valid, jnp.int32).reshape(B, 1))
     ok &= (phys >= 0) & (pg < npg)
-    row = jnp.where(ok, phys, P1 - 1)                          # trash page
-    idx = (row * page + off).reshape(B * C)
+    row = jnp.where(ok, phys, P1 - 1).reshape(B * C)          # trash page
+    off = off.reshape(B * C)
 
-    def put(pool, new):  # pool (P1, page, ...), new (B, C, ...)
-        flat = pool.reshape((P1 * page,) + pool.shape[2:])
-        flat = flat.at[idx].set(
+    def put(pool, new):  # pool (P1, K, page, ...), new (B, C, K, ...)
+        return pool.at[row, :, off].set(
             new.reshape((B * C,) + new.shape[2:]).astype(pool.dtype))
-        return flat.reshape(pool.shape)
 
     out = dict(cache)
     if "kp_scale" in cache:
@@ -400,10 +402,10 @@ def paged_copy_pages(cache, src, dst):
     """Copy-on-write content copy: pool pages ``src[i] -> dst[i]``.
 
     ``cache`` is one paged-attention pool dict (``kp``/``vp`` + optional
-    int8 scales), either per-layer ``(n_pages+1, page, ...)`` or stacked
-    ``(n_blocks, n_pages+1, page, ...)``. The copy runs before the
-    owning slot's next ``paged_cache_insert`` writes into ``dst``, so a
-    shared source page is never mutated.
+    int8 scales), either per-layer ``(n_pages+1, K, page, ...)`` or
+    stacked ``(n_blocks, n_pages+1, K, page, ...)``. The copy runs
+    before the owning slot's next ``paged_cache_insert`` writes into
+    ``dst``, so a shared source page is never mutated.
     """
     s = jnp.asarray(src, jnp.int32)
     d = jnp.asarray(dst, jnp.int32)

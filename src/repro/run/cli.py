@@ -17,12 +17,33 @@ import argparse
 import dataclasses
 import os
 import sys
+from pathlib import Path
 
 from repro.run.overrides import SpecError, apply_assignments
 from repro.run.spec import MESHES, MODES, SCENARIOS, RunSpec
 from repro.run.specfile import load_spec_file
 
 _USAGE = "usage: python -m repro run [--spec F] [--arch A] [--mode M] ..."
+# Persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset: one
+# fixed, git-ignored path in the checkout (the path is part of the key,
+# so a directory that moves never hits).
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here; otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE`.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def build_spec(args) -> RunSpec:
@@ -83,6 +104,7 @@ def main(argv=None) -> int:
 
         os.environ["XLA_FLAGS"] = dryrun_xla_flags()
 
+    use_compile_cache()
     from repro.run.dispatch import run_spec
 
     # run_spec stores the structured result in dispatch.LAST_RESULT for

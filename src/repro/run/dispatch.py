@@ -49,10 +49,16 @@ def resolve_config(spec: RunSpec):
 
 
 def build_mesh(spec: RunSpec):
-    from repro.launch.mesh import make_production_mesh, single_device_mesh
+    from repro.launch.mesh import (
+        local_mesh,
+        make_production_mesh,
+        single_device_mesh,
+    )
 
     if spec.mesh == "single":
         return single_device_mesh()
+    if spec.mesh == "local":
+        return local_mesh()
     return make_production_mesh(multi_pod=spec.mesh == "multipod")
 
 

@@ -30,7 +30,10 @@ from repro.run.overrides import (
 )
 
 MODES = ("train", "eval", "serve", "bench", "dryrun")
-MESHES = ("single", "pod", "multipod")
+# single: one device (1x1); local: every device this process sees, as a
+# (data, model) grid (2x2 on a four-chip host); pod/multipod: the
+# production 16x16 / 2x16x16 meshes.
+MESHES = ("single", "local", "pod", "multipod")
 # The four MLPerf-Inference scenarios; mirrors serve.scenarios.SCENARIOS
 # (kept literal so spec parsing stays jax-free; a drift test in
 # tests/test_scenarios.py asserts the two agree).

@@ -278,7 +278,7 @@ class PagePool:
 def apply_defrag(cache, perm):
     """Gather every paged pool leaf into the post-``defrag`` page order.
 
-    Leaves are ``(n_blocks, n_pages + 1, page, ...)``; ``perm`` comes
+    Leaves are ``(n_blocks, n_pages + 1, K, page, ...)``; ``perm`` comes
     from :meth:`PagePool.defrag`. Dense entries (enc-dec ``cross``
     slabs, recurrent states) are left untouched.
     """

@@ -24,7 +24,8 @@ from repro.core import weight_update_sharding as WUS
 from repro.kernels import ref as kref
 from repro.optim import adam, constant, lars, sgd_momentum
 
-from repro.dist.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 MESH = make_mesh((4, 2), ("data", "model"),
                  axis_types=(AxisType.Auto,) * 2)
@@ -174,6 +175,47 @@ def check_graph_partitioning_equivalence():
     print("OK graph_partitioning")
 
 
+def check_kernels_per_shard():
+    """Pallas kernels (interpret mode) under the 4x2 mesh run per shard
+    (``kernels.ops._per_shard``) and equal the unsharded oracles: query
+    and KV heads split alike over ``model`` (4 and 2), or stay whole
+    when the KV heads cannot split (6 and 3)."""
+    from repro.dist import Rules, use_rules
+    from repro.kernels import ops
+
+    os.environ["REPRO_USE_PALLAS"] = "interpret"
+    try:
+        rules = Rules(MESH, "fsdp")
+        ks = jax.random.split(KEY, 3)
+        B, D, page, npg = 4, 32, 4, 4
+        for H, K in ((4, 2), (6, 3)):
+            q = jax.random.normal(ks[0], (B, 32, H, D))
+            k = jax.random.normal(ks[1], (B, 32, K, D))
+            v = jax.random.normal(ks[2], (B, 32, K, D))
+            with MESH, use_rules(rules):
+                got = jax.jit(ops.attention)(q, k, v)
+            want = kref.attention(q, k, v, causal=True)
+            assert float(jnp.abs(want - got).max()) < 1e-4, (H, K)
+
+            C = 3
+            kp = jax.random.normal(ks[1], (B * npg, K, page, D))
+            vp = jax.random.normal(ks[2], (B * npg, K, page, D))
+            pt = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)[::-1]
+            nv = jnp.asarray([3, 1, 2, 3], jnp.int32)
+            pos = jnp.asarray([0, 9, 13, 5], jnp.int32)
+            qc = q[:, :C]
+            with MESH, use_rules(rules):
+                got = jax.jit(lambda *a: ops.paged_attention(
+                    *a, pos=pos, n_valid=nv))(qc, kp, vp, pt)
+            want = kref.paged_attention(qc, kp, vp, pt, pos=pos, n_valid=nv)
+            for b in range(B):
+                n = int(nv[b])
+                assert float(jnp.abs(want[b, :n] - got[b, :n]).max()) < 1e-4
+    finally:
+        del os.environ["REPRO_USE_PALLAS"]
+    print("OK kernels_per_shard")
+
+
 if __name__ == "__main__":
     check_gradsum_2d_equals_sum()
     check_flatten_roundtrip()
@@ -185,4 +227,5 @@ if __name__ == "__main__":
     check_distributed_bn()
     check_sharded_trainer_matches_single_device()
     check_graph_partitioning_equivalence()
+    check_kernels_per_shard()
     print("ALL_DIST_CHECKS_PASSED")

@@ -105,7 +105,7 @@ def test_paged_cache_insert_lands_in_mapped_pages():
     K, hd = cfg.n_kv_heads, cfg.head_dim
     page, n_pages = 4, 6
     cache = L.init_paged_kv_cache(cfg, n_pages, page)
-    assert cache["kp"].shape == (n_pages + 1, page, K, hd)  # + trash page
+    assert cache["kp"].shape == (n_pages + 1, K, page, hd)  # + trash page
     pt = jnp.asarray([[2, 5, -1], [4, -1, -1]], jnp.int32)
     B, C = 2, 3
     k = jax.random.normal(KEY, (B, C, K, hd))
@@ -116,10 +116,10 @@ def test_paged_cache_insert_lands_in_mapped_pages():
         jnp.asarray([3, 1], jnp.int32))
     kp = np.asarray(out["kp"], np.float32)
     kf = np.asarray(k, np.float32)
-    np.testing.assert_allclose(kp[2, 3], kf[0, 0], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[5, 0], kf[0, 1], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[5, 1], kf[0, 2], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[4, 1], kf[1, 0], rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(kp[2, :, 3], kf[0, 0], rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(kp[5, :, 0], kf[0, 1], rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(kp[5, :, 1], kf[0, 2], rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(kp[4, :, 1], kf[1, 0], rtol=1e-2, atol=1e-2)
     # row 1's masked tokens went to the trash page, not a real one
     assert np.abs(kp[:n_pages]).astype(bool).sum() == 4 * K * hd
 
@@ -139,8 +139,8 @@ def test_paged_cache_insert_int8_roundtrip():
            * np.asarray(out["kp_scale"])[..., None])
     # positions 2..5 -> page1[2], page1[3], page0[0], page0[1]
     for i, (phys, off) in enumerate(((1, 2), (1, 3), (0, 0), (0, 1))):
-        err = np.abs(deq[phys, off] - np.asarray(k)[0, i])
-        step = np.asarray(out["kp_scale"])[phys, off][..., None]
+        err = np.abs(deq[phys, :, off] - np.asarray(k)[0, i])
+        step = np.asarray(out["kp_scale"])[phys, :, off][..., None]
         assert float((err - step).max()) < 1e-5
 
 
@@ -157,7 +157,7 @@ def test_paged_cache_insert_int4_roundtrip():
     K, hd = cfg.n_kv_heads, cfg.head_dim
     page, n_pages = 4, 3
     cache = L.init_paged_kv_cache(cfg, n_pages, page)
-    assert cache["kp"].shape == (n_pages + 1, page, K, hd // 2)
+    assert cache["kp"].shape == (n_pages + 1, K, page, hd // 2)
     assert cache["kp"].dtype == jnp.int8  # packed nibbles
     pt = jnp.asarray([[1, 0]], jnp.int32)
     k = jax.random.normal(KEY, (1, 4, K, hd)) * 3.0
@@ -166,8 +166,8 @@ def test_paged_cache_insert_int4_roundtrip():
         jnp.asarray([4], jnp.int32))
     deq = np.asarray(quant.dequantize(out["kp"], out["kp_scale"], hd))
     for i, (phys, off) in enumerate(((1, 2), (1, 3), (0, 0), (0, 1))):
-        err = np.abs(deq[phys, off] - np.asarray(k)[0, i])
-        step = np.asarray(out["kp_scale"])[phys, off][..., None]
+        err = np.abs(deq[phys, :, off] - np.asarray(k)[0, i])
+        step = np.asarray(out["kp_scale"])[phys, :, off][..., None]
         assert float((err - step).max()) < 1e-5
 
 

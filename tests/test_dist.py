@@ -1,5 +1,5 @@
 """repro.dist subsystem tests beyond test_sharding.py: scan-stacked
-tagging, mesh-context constrain scoping, 3-axis wus Rules, compat shim."""
+tagging, mesh-context constrain scoping, 3-axis wus Rules, shard_map."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -163,12 +163,12 @@ def test_tp2d_keeps_batch_off_data():
 
 
 # --------------------------------------------------------------------------- #
-# compat shim: shard_map accepts check_vma on this JAX, decorator + partial.
+# jax.shard_map with check_vma, called directly and as a partial decorator.
 # --------------------------------------------------------------------------- #
 def test_compat_shard_map_runs():
     import functools
 
-    from repro.dist.compat import shard_map
+    from jax import shard_map
 
     mesh = single_device_mesh()
 

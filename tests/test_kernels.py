@@ -60,6 +60,44 @@ def test_flash_attention_k_offset_negative_positions_masked():
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize(
+    "Sq,Sk,H,K,causal,window,q_offset,k_offset",
+    [
+        (48, 48, 4, 2, True, None, 0, 0),       # causal GQA
+        (40, 40, 2, 2, True, 12, 0, 0),         # sliding window
+        (24, 24, 4, 1, False, None, 0, 0),      # MQA, bidirectional
+        (1, 37, 4, 2, True, None, 36, 0),       # decode-like
+        (32, 48, 2, 2, True, 16, 0, -16),       # halo at negative positions
+    ],
+)
+def test_flash_attention_custom_vjp_matches_chunked_grad(
+        Sq, Sk, H, K, causal, window, q_offset, k_offset, monkeypatch):
+    """The flash kernel's custom VJP (XLA backward from the saved
+    log-sum-exp) == jax.grad through the chunked jnp attention, with
+    backward tiles smaller than the sequence so the tile loops and the
+    causal/window band skipping are exercised."""
+    monkeypatch.setattr(fa, "BWD_BLOCK", 16)
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (2, Sq, H, 32))
+    k = jax.random.normal(ks[1], (2, Sk, K, 32))
+    v = jax.random.normal(ks[2], (2, Sk, K, 32))
+    ct = jax.random.normal(ks[3], (2, Sq, H, 32))
+    kw = dict(causal=causal, window=window, q_offset=q_offset,
+              k_offset=k_offset, scale=None)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * ct)
+
+    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, interpret=True, block_q=16, block_k=16, **kw)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ops._chunked_attention(
+        q, k, v, chunk=16, **kw)), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-5)
+
+
 def test_chunked_jnp_attention_vs_ref():
     ks = jax.random.split(KEY, 3)
     for (Sq, Sk, chunk) in [(128, 128, 32), (100, 100, 48), (1, 77, 16)]:
@@ -182,8 +220,8 @@ def test_block_skip_attention_property(nq, ragged, window, chunk):
 def _paged_case(seed, B, C, H, K, D, page, P, npg, lens, nvs, dtype):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, C, H, D), dtype)
-    kp = jax.random.normal(ks[1], (P, page, K, D), dtype)
-    vp = jax.random.normal(ks[2], (P, page, K, D), dtype)
+    kp = jax.random.normal(ks[1], (P, K, page, D), dtype)
+    vp = jax.random.normal(ks[2], (P, K, page, D), dtype)
     rng = np.random.RandomState(seed)
     pt = np.full((B, npg), -1, np.int32)
     pos = np.zeros((B,), np.int32)
@@ -253,13 +291,13 @@ def test_paged_attention_matches_dense_decode():
                                 dense["slot_pos"], pos=S)
     # paged pool with the same K/V scattered into mapped pages
     pt = jnp.asarray([[3, 0], [1, 2]], jnp.int32)
-    kp = jnp.zeros((5, page, K, D))
-    vp = jnp.zeros((5, page, K, D))
+    kp = jnp.zeros((5, K, page, D))
+    vp = jnp.zeros((5, K, page, D))
     for b in range(B):
         for t in range(S + 1):
             phys = int(pt[b, t // page])
-            kp = kp.at[phys, t % page].set(k[b, t])
-            vp = vp.at[phys, t % page].set(v[b, t])
+            kp = kp.at[phys, :, t % page].set(k[b, t])
+            vp = vp.at[phys, :, t % page].set(v[b, t])
     got = ops.paged_attention(
         q, kp, vp, pt, pos=jnp.full((B,), S, jnp.int32),
         n_valid=jnp.ones((B,), jnp.int32))
@@ -300,12 +338,9 @@ def test_quantize_bounded_error(qz, lim):
 
 
 def _quantize_pool(kp, vp, qz):
-    P, page, K, D = kp.shape
-    kq, ks = qz(kp.reshape(P * page, K, D))
-    vq, vs = qz(vp.reshape(P * page, K, D))
-    sh = kq.shape[-1]
-    return (kq.reshape(P, page, K, sh), vq.reshape(P, page, K, sh),
-            ks.reshape(P, page, K), vs.reshape(P, page, K))
+    kq, ks = qz(kp)  # per-(page, head, token) scales over head_dim
+    vq, vs = qz(vp)
+    return kq, vq, ks, vs
 
 
 @pytest.mark.parametrize("qdtype", ["int8", "int4"])
