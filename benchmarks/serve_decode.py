@@ -47,7 +47,7 @@ from repro.serve.engine import synthetic_requests
 from repro.serve.scenarios import SCENARIOS, make_trace, scenario_driver
 from repro.train.steps import ModelAPI
 
-DERIVED = ("tokens_per_s", "p50_token_ms", "p99_token_ms", "ttft_p50_ms",
+DERIVED = ("tokens_per_s", "ttft_p50_ms",
            "mean_batch_occupancy", "requests", "pool_util_mean",
            "pool_util_peak", "preemptions", "prefix_hit_rate",
            "pages_shared", "prefill_tokens_skipped", "cow_copies",
@@ -123,8 +123,6 @@ def run(ctx):
             f"serve/{cfg.name}_{scenario}",
             _decode_timing(report),
             tokens_per_s=s["tokens_per_s"],
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             mean_batch_occupancy=s["mean_batch_occupancy"],
             requests=s["requests"],
@@ -154,8 +152,6 @@ def run(ctx):
             f"serve/{cfg.name}_paged_{scenario}",
             _decode_timing(report),
             tokens_per_s=s["tokens_per_s"],
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             pool_util_mean=s["pool_util_mean"],
             pool_util_peak=s["pool_util_peak"],
@@ -187,8 +183,6 @@ def run(ctx):
             tokens_per_s=s["tokens_per_s"],
             tokens_per_s_vs_bf16=round(
                 s["tokens_per_s"] / max(paged_tps[scenario], 1e-9), 4),
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             pool_util_mean=s["pool_util_mean"],
             pool_util_peak=s["pool_util_peak"],
@@ -222,8 +216,6 @@ def run(ctx):
                 s["tokens_per_s"] / max(paged_tps[scenario], 1e-9), 4),
             spec_accept_rate=report.spec_accept_rate,
             draft_tokens=report.draft_tokens,
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             preemptions=report.preemptions,
             requests=s["requests"],
@@ -264,8 +256,6 @@ def run(ctx):
             f"serve/{cfg.name}_prefix_{scenario}",
             _decode_timing(report),
             tokens_per_s=s["tokens_per_s"],
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             ttft_delta_ms=round(
                 s["ttft_p50_ms"] - baseline.summary()["ttft_p50_ms"], 3),
@@ -296,7 +286,6 @@ def run(ctx):
             f"serve/{cfg.name}_slo_{scenario}",
             _decode_timing(report),
             tokens_per_s=s["tokens_per_s"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             slo_goodput=s["slo_goodput"],
             slo_violations=s["slo_violations"],
@@ -346,8 +335,6 @@ def run(ctx):
             lost_tokens=s["lost_tokens"],
             reroutes=s["reroutes"],
             fleet_replicas=s["replicas"],
-            p50_token_ms=s["p50_token_ms"],
-            p99_token_ms=s["p99_token_ms"],
             ttft_p50_ms=s["ttft_p50_ms"],
             requests=s["requests"],
         )

@@ -112,8 +112,6 @@ class FleetReport:
             "reroutes": self.reroutes,
             "routing_hit_rate": round(self.routing_hit_rate, 4),
             "fleet_steps": self.fleet_steps,
-            "p50_token_ms": m["p50_token_ms"],
-            "p99_token_ms": m["p99_token_ms"],
             "ttft_p50_ms": m["ttft_p50_ms"],
         }
 

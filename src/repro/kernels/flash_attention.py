@@ -145,6 +145,7 @@ def _forward(q, k, v, o: _Opts):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=o.interpret,
+        name="flash_attention_fwd",
     )(qp, kp, vp)
     return out[:, :, :Sq], lse[:, :, :Sq, 0]
 
@@ -235,7 +236,8 @@ def _flash_fwd(q, k, v, o: _Opts):
 
 
 def _flash_bwd(o: _Opts, res, dout):
-    return _backward(o, *res, dout)
+    with jax.named_scope("attention_bwd"):
+        return _backward(o, *res, dout)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

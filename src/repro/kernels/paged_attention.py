@@ -199,5 +199,6 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, C, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(pt_safe, posv, nv, *operands)
     return jnp.swapaxes(out, 1, 2)

@@ -145,7 +145,8 @@ def attention_full(params, x, cfg: ModelConfig, *, positions, window=None,
     q = constrain(q, "batch", None, "act_heads", None)
     k = constrain(k, "batch", None, "act_heads", None)
     v = constrain(v, "batch", None, "act_heads", None)
-    out = ops.attention(q, k, v, causal=causal, window=window)
+    with jax.named_scope("attention"):
+        out = ops.attention(q, k, v, causal=causal, window=window)
     out = constrain(out, "batch", None, "act_heads", None)
     wo = params["wo"][0] if isinstance(params["wo"], tuple) else params["wo"]
     y = jnp.einsum("bshk,hkd->bsd", out, wo.astype(_cdtype(cfg)))
@@ -360,8 +361,14 @@ def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid):
     page`` within the row's mapped physical page. Tokens at i >=
     n_valid[b] (and any position whose page is unmapped) are routed to
     the trash page. The engine guarantees every valid position's page is
-    mapped before the step runs.
+    mapped before the step runs. Its operations carry the name scope
+    ``kv_insert`` in the compiled program.
     """
+    with jax.named_scope("kv_insert"):
+        return _paged_insert(cache, k_new, v_new, page_table, pos, n_valid)
+
+
+def _paged_insert(cache, k_new, v_new, page_table, pos, n_valid):
     P1, _, page = cache["kp"].shape[:3]
     B, C, K, hd = k_new.shape
     npg = page_table.shape[1]
@@ -439,12 +446,13 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
         k_new = apply_rope(k_new, posm, theta=cfg.rope_theta, mrope=mr)
     new_cache = paged_cache_insert(
         cache, k_new, v_new, page_table, pos, n_valid)
-    out = ops.paged_attention(
-        q, new_cache["kp"], new_cache["vp"], page_table,
-        pos=pos, n_valid=n_valid, window=window,
-        kp_scale=new_cache.get("kp_scale"),
-        vp_scale=new_cache.get("vp_scale"),
-    )
+    with jax.named_scope("attention"):
+        out = ops.paged_attention(
+            q, new_cache["kp"], new_cache["vp"], page_table,
+            pos=pos, n_valid=n_valid, window=window,
+            kp_scale=new_cache.get("kp_scale"),
+            vp_scale=new_cache.get("vp_scale"),
+        )
     wo = params["wo"][0] if isinstance(params["wo"], tuple) else params["wo"]
     y = jnp.einsum("bshk,hkd->bsd", out, wo.astype(_cdtype(cfg)))
     return y, new_cache

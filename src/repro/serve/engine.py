@@ -467,7 +467,28 @@ class Engine:
 
     # ------------------------------------------------------------------ #
     def step(self) -> None:
-        """One scheduling round: arrivals -> admissions -> batched step."""
+        """One scheduling round: arrivals -> admissions -> batched step.
+
+        On the paged layout the round's phases run inside profiler spans
+        (``jax.profiler.TraceAnnotation``, which records only while a
+        profiler runs): ``repro.serve.schedule`` (arrivals, admission,
+        page growth and preemption), ``repro.serve.prepare`` (the
+        program's host inputs), ``repro.serve.device`` (dispatch until
+        the sampled tokens are on the host) and ``repro.serve.emit``
+        (tokens appended, rows retired)."""
+        if self.layout == "paged":
+            with jax.profiler.TraceAnnotation("repro.serve.schedule"):
+                self._arrive_and_admit()
+                plan = self._chunk_plan() if self.sched.n_active else ()
+            if plan:
+                self._chunk_once(*plan)
+        else:
+            self._arrive_and_admit()
+            if self.sched.n_active:
+                self._decode_once()
+        self._step_idx += 1
+
+    def _arrive_and_admit(self) -> None:
         while self._arrivals and self._arrivals[0][0] <= self._step_idx:
             _, _, req = heapq.heappop(self._arrivals)
             if req.t_arrival is None:
@@ -478,12 +499,6 @@ class Engine:
                  else self._admit_slab)
         for slot, req in self.sched.admit():
             admit(slot, req)
-        if self.sched.n_active:
-            if self.layout == "paged":
-                self._chunk_once()
-            else:
-                self._decode_once()
-        self._step_idx += 1
 
     def compiled_programs(self) -> dict:
         """Program name -> jit cache size (compiled specializations).
@@ -609,23 +624,13 @@ class Engine:
                 drafts[slot] = [int(t) for t in d]
         return drafts
 
-    def _chunk_once(self) -> None:
-        """One mixed dispatch: every occupied slot advances — decode rows
-        by one token (plus any speculative draft), prefilling rows by up
-        to ``prefill_chunk`` prompt tokens — through the single compiled
-        chunk program.
-
-        Speculative decode rides the same dispatch: a decode row feeds
-        [last_tok, d_1..d_k] with n_valid = 1+k; the full-logits head
-        gives argmax targets at every fed position, the accepted prefix
-        is the run of drafts matching those targets, and the row emits
-        accept+1 tokens (the +1 is the model's own next token — free,
-        and exactly what non-speculative greedy would produce next)."""
-        C = self.scfg.prefill_chunk
-        B = self.scfg.max_batch
+    def _chunk_plan(self):
+        """The rows the next chunk dispatch feeds, and their drafts:
+        ``(active, drafts)``, after growing decode rows' pages (and
+        shedding drafts, evicting cached prefixes or preempting when the
+        pool runs dry); ``()`` when no row is left."""
         active = dict(self.sched.running())
-        spec = self._drafter is not None
-        drafts = self._draft(active) if spec else {}
+        drafts = self._draft(active) if self._drafter is not None else {}
 
         # Lazy decode growth; when the pool runs dry, first shed drafts
         # (verifying fewer tokens is strictly cheaper than evicting KV),
@@ -661,9 +666,40 @@ class Engine:
             self._preempt_slot(victim)
             active.pop(victim)
             drafts.pop(victim, None)
-        if not active:
-            return
+        return (active, drafts) if active else ()
 
+    def _chunk_once(self, active: dict, drafts: dict) -> None:
+        """One mixed dispatch: every row of ``active`` advances — decode
+        rows by one token (plus any speculative draft), prefilling rows
+        by up to ``prefill_chunk`` prompt tokens — through the single
+        compiled chunk program.
+
+        Speculative decode rides the same dispatch: a decode row feeds
+        [last_tok, d_1..d_k] with n_valid = 1+k; the full-logits head
+        gives argmax targets at every fed position, the accepted prefix
+        is the run of drafts matching those targets, and the row emits
+        accept+1 tokens (the +1 is the model's own next token — free,
+        and exactly what non-speculative greedy would produce next)."""
+        with jax.profiler.TraceAnnotation("repro.serve.prepare"):
+            toks, nv, posb, prefilling = self._chunk_inputs(active, drafts)
+        with jax.profiler.TraceAnnotation("repro.serve.device"):
+            t0 = time.perf_counter()
+            nxt = self._chunk_device(toks, nv, posb)
+            dt = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("repro.serve.emit"):
+            produced = self._chunk_emit(active, drafts, nv, nxt)
+            self._trace.append(StepTrace(
+                "mixed" if prefilling else "decode", dt, produced,
+                pool_util=self._pool.utilization(),
+                n_fed=sum(int(nv[slot]) for slot in active),
+                n_positions=toks.size))
+
+    def _chunk_inputs(self, active: dict, drafts: dict):
+        """The chunk program's host inputs: tokens (B, C), fed counts,
+        start positions, and whether any row is still prefilling; the
+        rows' page tables are refreshed in ``_ptab``."""
+        C = self.scfg.prefill_chunk
+        B = self.scfg.max_batch
         toks = np.zeros((B, C), np.int32)
         nv = np.ones((B,), np.int32)
         posb = np.zeros((B,), np.int32)
@@ -684,21 +720,25 @@ class Engine:
                     nv[slot] = 1 + len(d)
             self._ptab[slot] = self._pool.table_row(
                 slot, self.scfg.max_pages)
+        return toks, nv, posb, prefilling
 
-        t0 = time.perf_counter()
+    def _chunk_device(self, toks, nv, posb) -> np.ndarray:
+        """Run the chunk program and sample; the next tokens on the host."""
         logits, self._cache = self._chunk_jit(
             self.params, jnp.asarray(toks), self._cache,
             jnp.asarray(self._ptab), jnp.asarray(posb), jnp.asarray(nv))
-        if spec:
+        if self._drafter is not None:
             # full-logits head: targets[b, i] is the model's next token
             # after fed position i (greedy — spec mode is argmax-only).
-            nxt = np.asarray(jax.block_until_ready(jnp.argmax(logits, -1)))
-        else:
-            # each row's sampled token sits right after its last fed token
-            nxt = np.asarray(jax.block_until_ready(
-                self._sample(logits, self._rid, posb + nv)))
-        dt = time.perf_counter() - t0
+            return np.asarray(jax.block_until_ready(jnp.argmax(logits, -1)))
+        # each row's sampled token sits right after its last fed token
+        return np.asarray(jax.block_until_ready(
+            self._sample(logits, self._rid, posb + nv)))
 
+    def _chunk_emit(self, active: dict, drafts: dict, nv, nxt) -> int:
+        """Advance each fed row, append its emitted tokens and retire
+        finished requests; returns the tokens emitted."""
+        spec = self._drafter is not None
         produced = 0
         for slot, req in active.items():
             n = int(nv[slot])
@@ -745,9 +785,7 @@ class Engine:
                 # the index slices (prompt + tokens)[:pos] and every
                 # position below _pos is now a verified token.
                 self._register(slot, req)
-        self._trace.append(StepTrace(
-            "mixed" if prefilling else "decode", dt, produced,
-            pool_util=self._pool.utilization()))
+        return produced
 
     def _retire_paged(self, slot: int, req: Request) -> None:
         self.sched.retire(slot)  # frees the slot's pages too
@@ -784,7 +822,8 @@ class Engine:
         req.tokens.append(tok)
         req.t_first_token = time.perf_counter()
         req.s_first_token = self._step_idx
-        self._trace.append(StepTrace("prefill", dt, 1))
+        self._trace.append(StepTrace("prefill", dt, 1, n_fed=P,
+                                     n_positions=pad_to))
         if req.done or tok == self.scfg.eos_id:
             self._retire_slab(slot, req)
         else:
@@ -811,7 +850,9 @@ class Engine:
             self._pos[slot] += 1
             if req.done or tok == self.scfg.eos_id:
                 self._retire_slab(slot, req)
-        self._trace.append(StepTrace("decode", dt, len(running)))
+        self._trace.append(StepTrace(
+            "decode", dt, len(running), n_fed=len(running),
+            n_positions=self.scfg.max_batch))
 
     def _retire_slab(self, slot: int, req: Request) -> None:
         self.sched.retire(slot)

@@ -1,15 +1,15 @@
-"""Serving metrics: throughput + latency percentiles.
+"""Serving metrics: throughput, time-to-first-token and per-class
+latency percentiles.
 
 MLPerf-Inference-style reporting (Reddi et al., 2019): the offline
 scenario cares about total throughput (tokens/s), the server scenario
-about the per-token latency tail (p50/p99) and time-to-first-token.
-Every decode step contributes one latency sample per token it produced;
-prefill contributes the first token of its request.
+about request latency and time-to-first-token. Each engine step leaves a
+``StepTrace`` of its device work and the tokens it fed.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 from repro.serve import slo as slo_mod
 
@@ -31,13 +31,19 @@ class StepTrace:
     mixed program reports 'decode' when every active row fed one token
     and 'mixed' while any row is still chunk-prefilling, plus 'encode'
     for enc-dec admissions. pool_util: fraction of the page pool in use
-    after the step (paged engine only).
+    after the step (paged engine only). n_fed: tokens the step's program
+    fed to active rows (prompt tokens, or one per decode row plus its
+    drafts); n_positions: token positions the dispatched program
+    computes (B x C for the chunk program), so n_fed / n_positions is the
+    share of the step's work that was real.
     """
 
     kind: str
     wall_s: float
     n_tokens: int  # tokens produced by this step
     pool_util: Optional[float] = None
+    n_fed: int = 0
+    n_positions: int = 0
 
 
 @dataclasses.dataclass
@@ -65,16 +71,6 @@ class ServeReport:
     @property
     def tokens_per_s(self) -> float:
         return self.tokens_generated / max(self.elapsed_s, 1e-9)
-
-    def token_latencies_s(self) -> List[float]:
-        out = []
-        for st in self.steps:
-            out.extend([st.wall_s] * st.n_tokens)
-        return out
-
-    def percentiles_ms(self) -> Tuple[float, float]:
-        lats = self.token_latencies_s()
-        return (percentile(lats, 50) * 1e3, percentile(lats, 99) * 1e3)
 
     # -- SLO accounting (serve.slo; MLPerf Server scenario + goodput) --- #
     @property
@@ -139,7 +135,6 @@ class ServeReport:
 
     # ------------------------------------------------------------------ #
     def summary(self) -> Dict[str, Any]:
-        p50, p99 = self.percentiles_ms()
         ttfts = [r.ttft_s for r in self.requests if r.ttft_s is not None]
         decode_steps = [s for s in self.steps if s.kind == "decode"]
         utils = [s.pool_util for s in self.steps if s.pool_util is not None]
@@ -173,8 +168,6 @@ class ServeReport:
             "tokens": self.tokens_generated,
             "elapsed_s": round(self.elapsed_s, 4),
             "tokens_per_s": round(self.tokens_per_s, 2),
-            "p50_token_ms": round(p50, 3),
-            "p99_token_ms": round(p99, 3),
             "ttft_p50_ms": round(percentile(ttfts, 50) * 1e3, 3),
             "decode_steps": len(decode_steps),
             "mean_batch_occupancy": round(
@@ -187,8 +180,6 @@ class ServeReport:
         return (
             f"{s['requests']} requests, {s['tokens']} tokens in "
             f"{s['elapsed_s']:.2f}s ({s['tokens_per_s']:.1f} tok/s), "
-            f"per-token p50 {s['p50_token_ms']:.1f}ms / "
-            f"p99 {s['p99_token_ms']:.1f}ms, "
             f"ttft p50 {s['ttft_p50_ms']:.1f}ms, "
             f"mean occupancy {s['mean_batch_occupancy']:.1f}"
         )

@@ -17,7 +17,7 @@ clock still flows into the per-class latency percentiles of
 
 Policy, in two places:
 
-* **Preemption under pool pressure** (``Engine._chunk_once`` growth):
+* **Preemption under pool pressure** (``Engine._chunk_plan`` growth):
   the victim is the slot with the **most slack** — the request that can
   best absorb a recompute-resume round-trip. Untagged requests have
   infinite slack, and ties break youngest-first (max admit seq), so a

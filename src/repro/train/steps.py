@@ -272,7 +272,9 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
             metrics_out = {"loss": loss, "nll": nll}
             if "grad_norm" in extra_metrics:
                 metrics_out["grad_norm"] = _global_norm(grads)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.update(
+                    grads, opt_state, params)
             if "param_norm" in extra_metrics:
                 metrics_out["param_norm"] = _global_norm(new_params)
             return ({"params": new_params, "opt": new_opt}, metrics_out)

@@ -235,7 +235,9 @@ def test_continuous_batching_matches_sequential(arch, mode):
     s = report.summary()
     assert s["tokens"] == sum(len(r.tokens) for r in reqs)
     assert s["tokens_per_s"] > 0
-    assert s["p99_token_ms"] >= s["p50_token_ms"] >= 0
+    # every prompt token is fed once, then each token but the last
+    assert sum(st.n_fed for st in report.steps) == sum(
+        r.prompt_len + len(r.tokens) - 1 for r in report.requests)
 
 
 @pytest.mark.slow
@@ -345,6 +347,25 @@ def test_paged_engine_token_identical_to_slab(arch, mode):
         assert utils and max(utils) <= 1.0
     else:
         assert eng.layout == "slab"
+
+
+def test_paged_steps_count_fed_tokens():
+    """A prompt of P tokens at chunk C streams in ceil(P / C) chunk
+    steps; each of the k decode steps after it feeds one token: the
+    steps' n_fed sum to P + k, each over the program's B x C positions."""
+    cfg = get_config("gemma-7b").reduced()
+    api = ModelAPI(cfg)
+    params, _ = split_tree(api.init(cfg, jax.random.PRNGKey(0)))
+    eng = Engine(cfg, params, None,
+                 ServeConfig(max_batch=2, max_len=32, kv_layout="paged",
+                             page_size=4, prefill_chunk=4))
+    P, k = 11, 3
+    report = run_offline(eng, [Request(prompt=[7] * P,
+                                       max_new_tokens=k + 1)])
+    assert [s.kind for s in report.steps] == ["mixed"] * 3 + ["decode"] * k
+    assert [s.n_fed for s in report.steps] == [4, 4, 3] + [1] * k
+    assert sum(s.n_fed for s in report.steps) == P + k
+    assert {s.n_positions for s in report.steps} == {2 * 4}
 
 
 @pytest.mark.slow

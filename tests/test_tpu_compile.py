@@ -134,3 +134,31 @@ def test_flash_attention_grad_compiles_sharded_2x2(topo, monkeypatch):
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)),
              *_qkv_shapes(spec, spec, B=4))
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "flash_attention_fwd"])
+def test_kernels_carry_their_names(one_chip, kernel):
+    """The trace names each kernel's custom call after its Pallas name,
+    so the readers need no operand shape to find it."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import paged_attention as pa
+
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    if kernel == "paged_attention":
+        n_pool = 8 * MAX_PAGES + 1
+        args = [S((8, 8, H, D), jnp.bfloat16),
+                S((n_pool, K, PAGE, D), jnp.bfloat16),
+                S((n_pool, K, PAGE, D), jnp.bfloat16),
+                S((8, MAX_PAGES), jnp.int32), S((8,), jnp.int32),
+                S((8,), jnp.int32)]
+        fn = lambda q, kp, vp, pt, pos, nv: pa.paged_attention(
+            q, kp, vp, pt, pos=pos, n_valid=nv)
+    else:
+        args = _qkv_shapes(one_chip, one_chip)
+        fn = lambda q, k, v: fa.flash_attention(q, k, v)
+    text = _compile(fn, *args).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(f"%{kernel}" in line.split(" = ")[0]
+                         for line in calls), calls[:1]
