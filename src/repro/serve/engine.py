@@ -692,7 +692,9 @@ class Engine:
                 "mixed" if prefilling else "decode", dt, produced,
                 pool_util=self._pool.utilization(),
                 n_fed=sum(int(nv[slot]) for slot in active),
-                n_positions=toks.size))
+                n_positions=toks.size,
+                n_kv_pages=sum(self._pool.pages_for(int(posb[s] + nv[s]))
+                               for s in active)))
 
     def _chunk_inputs(self, active: dict, drafts: dict):
         """The chunk program's host inputs: tokens (B, C), fed counts,

@@ -35,7 +35,10 @@ class StepTrace:
     fed to active rows (prompt tokens, or one per decode row plus its
     drafts); n_positions: token positions the dispatched program
     computes (B x C for the chunk program), so n_fed / n_positions is the
-    share of the step's work that was real.
+    share of the step's work that was real. n_kv_pages: page slots the
+    paged attention kernel visits in one layer (sum over active rows of
+    ceil((pos + n_valid) / page)); over B x max_pages it is the share of
+    the page table the step's attention reads.
     """
 
     kind: str
@@ -44,6 +47,7 @@ class StepTrace:
     pool_util: Optional[float] = None
     n_fed: int = 0
     n_positions: int = 0
+    n_kv_pages: int = 0
 
 
 @dataclasses.dataclass

@@ -234,17 +234,47 @@ def _paged_case(seed, B, C, H, K, D, page, P, npg, lens, nvs, dtype):
         np.asarray(nvs, np.int32))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("window", [None, 5])
-def test_paged_attention_kernel_vs_ref(dtype, window):
+# Kernel cases: "small" is a ragged mixed batch in one page block: a deep
+# decode row, a mid-prefill chunk row and a short row. "blocks" and
+# "blocks_c1" (G = 8, C = 16 and C = 1) span up to three 128-token page
+# blocks with ragged tails: a 300-token row, an empty row (n_valid 0,
+# table all -1), a row with an unmapped page inside its range, a row
+# sharing a physical page with the first, and queries on both sides of a
+# block boundary (positions 127..130), which a window of 5 crosses.
+_BLOCK_LENS, _BLOCK_NVS = [300, 0, 200, 140, 131], [16, 0, 1, 5, 4]
+
+
+def _paged_kernel_case(case, seed, dtype):
+    if case == "small":
+        lens, nvs = [13, 6, 2], [1, 4, 2]
+        return nvs, _paged_case(seed, 3, 4, 4, 2, 32, 4, 12, 8, lens, nvs,
+                                dtype)
+    C = 16 if case == "blocks" else 1
+    nvs = [min(n, C) for n in _BLOCK_NVS]
+    q, kp, vp, pt, pos, nv = _paged_case(seed, 5, C, 8, 1, 32, 16, 96, 20,
+                                         _BLOCK_LENS, nvs, dtype)
+    pt = pt.at[2, 3].set(-1).at[3, 0].set(pt[0, 0])  # a hole; a shared page
+    return nvs, (q, kp, vp, pt, pos, nv)
+
+
+def _paged_params(values):
+    """(case, window, value) parameters for each (id, value) of
+    ``values``; the "small" ones keep the ids they had as the only case."""
+    return [pytest.param(case, w, v, id="-".join(
+                ([] if case == "small" else [case]) + [str(w), vid]))
+            for case in ("small", "blocks", "blocks_c1")
+            for w in (None, 5) for vid, v in values]
+
+
+@pytest.mark.parametrize("case,window,dtype", _paged_params(
+    [("float32", jnp.float32), ("bfloat16", jnp.bfloat16)]))
+def test_paged_attention_kernel_vs_ref(case, window, dtype):
     """Pallas kernel (interpret) == oracle on the valid region of a
-    ragged mixed batch: a deep decode row, a mid-prefill chunk row and a
-    short row; entries past n_valid are garbage by contract."""
+    ragged batch (cases above); entries past n_valid are garbage by
+    contract, and a row with no tokens writes zeros."""
     from repro.kernels import paged_attention as pa
 
-    lens, nvs = [13, 6, 2], [1, 4, 2]
-    q, kp, vp, pt, pos, nv = _paged_case(
-        3, 3, 4, 4, 2, 32, 4, 12, 8, lens, nvs, dtype)
+    nvs, (q, kp, vp, pt, pos, nv) = _paged_kernel_case(case, 3, dtype)
     want = ref.paged_attention(q, kp, vp, pt, pos=pos, n_valid=nv,
                                window=window)
     got = pa.paged_attention(q, kp, vp, pt, pos=pos, n_valid=nv,
@@ -253,6 +283,8 @@ def test_paged_attention_kernel_vs_ref(dtype, window):
         np.testing.assert_allclose(
             np.asarray(got[b, :n], np.float32),
             np.asarray(want[b, :n], np.float32), **_tol(dtype))
+        if n == 0:
+            assert not np.any(np.asarray(got[b], np.float32))
 
 
 def test_paged_attention_ops_fallback_vs_ref():
@@ -343,18 +375,19 @@ def _quantize_pool(kp, vp, qz):
     return kq, vq, ks, vs
 
 
-@pytest.mark.parametrize("qdtype", ["int8", "int4"])
-@pytest.mark.parametrize("window", [None, 5])
-def test_paged_attention_quantized_kernel_vs_ref(qdtype, window):
+@pytest.mark.parametrize("case,window,qdtype", [
+    p for p in _paged_params([("int8", "int8"), ("int4", "int4")])
+    if p.values[0] != "blocks_c1"])
+def test_paged_attention_quantized_kernel_vs_ref(case, window, qdtype):
     """Quantized-pool Pallas kernel (in-kernel dequant, fp32 accumulation)
-    == the scale-aware oracle on the valid region of a ragged mixed
-    batch, for both int8 and packed-int4 pools."""
+    == the scale-aware oracle on the valid region of a ragged batch, in
+    one page block and across blocks, for both int8 and packed-int4
+    pools."""
     from repro.kernels import paged_attention as pa
 
     qz = quant.quantize_int8 if qdtype == "int8" else quant.quantize_int4
-    lens, nvs = [13, 6, 2], [1, 4, 2]
-    q, kp, vp, pt, pos, nv = _paged_case(
-        3, 3, 4, 4, 2, 32, 4, 12, 8, lens, nvs, jnp.float32)
+    nvs, (q, kp, vp, pt, pos, nv) = _paged_kernel_case(case, 3,
+                                                       jnp.float32)
     kpq, vpq, ks, vs = _quantize_pool(kp, vp, qz)
     want = ref.paged_attention(q, kpq, vpq, pt, pos=pos, n_valid=nv,
                                window=window, kp_scale=ks, vp_scale=vs)

@@ -368,6 +368,27 @@ def test_paged_steps_count_fed_tokens():
     assert {s.n_positions for s in report.steps} == {2 * 4}
 
 
+def test_paged_steps_count_kv_pages():
+    """n_kv_pages is the page slots the paged kernel visits in a layer:
+    the sum over active rows of ceil((pos + n_valid) / page). Prompts of
+    11 and 6 tokens at page 4, chunk 4: row A feeds (pos, n) = (0, 4),
+    (4, 4), (8, 3), then decodes at 11, 12, 13; row B feeds (0, 4),
+    (4, 2), then decodes once at 6 and finishes."""
+    cfg = get_config("gemma-7b").reduced()
+    api = ModelAPI(cfg)
+    params, _ = split_tree(api.init(cfg, jax.random.PRNGKey(0)))
+    eng = Engine(cfg, params, None,
+                 ServeConfig(max_batch=2, max_len=32, kv_layout="paged",
+                             page_size=4, prefill_chunk=4))
+    report = run_offline(eng, [Request(prompt=[7] * 11, max_new_tokens=4),
+                               Request(prompt=[5] * 6, max_new_tokens=2)])
+    pages = lambda pos, n: -(-(pos + n) // 4)
+    want = [pages(0, 4) + pages(0, 4), pages(4, 4) + pages(4, 2),
+            pages(8, 3) + pages(6, 1), pages(11, 1), pages(12, 1),
+            pages(13, 1)]
+    assert [s.n_kv_pages for s in report.steps] == want == [2, 4, 5, 3, 4, 4]
+
+
 @pytest.mark.slow
 def test_paged_preemption_and_defrag_keep_tokens_identical():
     """A pool too small for the workload forces preemptions; preempted

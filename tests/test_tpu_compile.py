@@ -62,21 +62,23 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("C", [8, 1])
-@pytest.mark.parametrize("pool", ["bfloat16", "int8", "int4"])
-def test_paged_attention_compiles_for_v5e(one_chip, C, pool):
+@pytest.mark.parametrize("B,C,max_pages,pool", [
+    pytest.param(8, C, MAX_PAGES, pool, id=f"{pool}-{C}")
+    for pool in ("bfloat16", "int8", "int4") for C in (8, 1)] + [
+    pytest.param(32, 16, 160, "bfloat16", id="chat-bfloat16-16")])
+def test_paged_attention_compiles_for_v5e(one_chip, B, C, max_pages, pool):
     """Serving shapes: max_batch 8, C = 8 (chunked prefill) and C = 1
-    (decode), head-major pool pages of 16 tokens."""
+    (decode), head-major pool pages of 16 tokens; and the chat cell's
+    32 rows of C = 16 over 160 pages (max_len 2560)."""
     from repro.kernels import paged_attention as pa
 
-    B = 8
-    n_pool = B * MAX_PAGES + 1
+    n_pool = B * max_pages + 1
     hd = D // 2 if pool == "int4" else D
     dt = jnp.bfloat16 if pool == "bfloat16" else jnp.int8
     S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                   sharding=one_chip)
     args = [S((B, C, H, D), jnp.bfloat16), S((n_pool, K, PAGE, hd), dt),
-            S((n_pool, K, PAGE, hd), dt), S((B, MAX_PAGES), jnp.int32),
+            S((n_pool, K, PAGE, hd), dt), S((B, max_pages), jnp.int32),
             S((B,), jnp.int32), S((B,), jnp.int32)]
     if pool != "bfloat16":
         args += [S((n_pool, K, PAGE), jnp.float32)] * 2
