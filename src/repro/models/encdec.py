@@ -241,22 +241,25 @@ def decode_chunk(params, cfg: ModelConfig, tokens, cache, page_table, pos,
     x = jnp.take(vals["embed"], tokens, axis=0).astype(dt)
     x = x + jnp.take(sinusoid_table(cfg, dt), positions, axis=0)
 
-    def block_fn(x, binp):
-        bp, cs, cc = binp
+    def block_fn(carry, binp):
+        x, cs = carry
+        bp, cc, layer = binp
         h = L.apply_norm(bp["norm1"], x, cfg)
-        y, ncs = L.attention_decode_paged(
+        y, cs = L.attention_decode_paged(
             bp["self_attn"], h, cfg, cs, page_table, pos, n_valid,
-            window=window)
+            layer=layer, window=window)
         x = x + y
         h = L.apply_norm(bp["norm_x"], x, cfg)
         x = x + L.attention_cross_chunk(bp["cross_attn"], h, cfg, cc)
         h = L.apply_norm(bp["norm2"], x, cfg)
         x = x + L.apply_ffn(bp["ffn"], h, cfg)
-        return x, ncs
+        return (x, cs), None
 
-    x, new_self = jax.lax.scan(
-        block_fn, x, (vals["dec_blocks"], cache["self"], cache["cross"])
-    )
+    # the self-attention pools ride the carry, as in lm.decode_chunk
+    (x, new_self), _ = jax.lax.scan(
+        block_fn, (x, cache["self"]),
+        (vals["dec_blocks"], cache["cross"],
+         jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = L.apply_norm(vals["dec_norm"], x, cfg)
     if full_logits:
         return _head(vals, cfg, x), {"self": new_self,

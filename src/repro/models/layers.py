@@ -348,9 +348,11 @@ def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
     conditional. Validity is carried by the page table (-1 = unmapped)
     plus per-row lengths, not by a per-slot ``slot_pos`` map.
 
-    Pages are head-major, ``(n_pages + 1, K, page, hd)`` (scales
-    ``(n_pages + 1, K, page)``), so one KV head's page is a
-    ``(page, hd)`` tile — the block the paged kernel DMAs.
+    Pages are head-major, ``(n_pages + 1, K, page // t, t * hd)`` (scales
+    ``(n_pages + 1, K, page)``): one KV head's page is ``page`` tokens of
+    ``hd`` values, ``t`` consecutive tokens to a row
+    (``ops.paged_tokens_per_row``), so the page read as ``(page, hd)`` is
+    the block the paged kernel takes.
     """
     K, hd = cfg.n_kv_heads, cfg.head_dim
     quantized = cfg.kv_cache_dtype in ("int8", "int4")
@@ -361,9 +363,10 @@ def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
                 f"int4 KV packs two dims per byte; head_dim {hd} is odd")
         store_hd = hd // 2  # two nibbles per byte (kernels.quant layout)
     dt = jnp.int8 if quantized else _cdtype(cfg)
+    t = ops.paged_tokens_per_row(page, store_hd)
     cache = {
-        "kp": jnp.zeros((n_pages + 1, K, page, store_hd), dt),
-        "vp": jnp.zeros((n_pages + 1, K, page, store_hd), dt),
+        "kp": jnp.zeros((n_pages + 1, K, page // t, t * store_hd), dt),
+        "vp": jnp.zeros((n_pages + 1, K, page // t, t * store_hd), dt),
     }
     if quantized:
         cache["kp_scale"] = jnp.zeros((n_pages + 1, K, page), jnp.float32)
@@ -371,26 +374,37 @@ def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
     return cache
 
 
-def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid):
-    """Scatter C new tokens' K/V into their rows' pages.
+def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid,
+                       layer):
+    """Scatter C new tokens' K/V into their rows' pages of one layer.
 
-    k_new/v_new: (B, C, K, hd). page_table: (B, max_pages) int32 physical
-    page ids (-1 unmapped). pos: (B,) absolute position of each row's
-    first token this step; token i of row b lands at logical position
-    ``pos[b] + i``, i.e. page ``(pos+i) // page``, offset ``(pos+i) %
-    page`` within the row's mapped physical page. Tokens at i >=
-    n_valid[b] (and any position whose page is unmapped) are routed to
-    the trash page. The engine guarantees every valid position's page is
-    mapped before the step runs. Its operations carry the name scope
-    ``kv_insert`` in the compiled program.
+    ``cache`` is one pool dict (``init_paged_kv_cache``) stacked over
+    layers, ``(n_blocks, n_pages + 1, K, ...)``, as the chunk program
+    carries it; ``layer`` picks the stacked layer written.
+    k_new/v_new: (B, C, K, hd). page_table: (B, max_pages) int32
+    physical page ids (-1 unmapped). pos: (B,) absolute position of each
+    row's first token this step; token i of row b lands at logical
+    position ``pos[b] + i``, i.e. page ``(pos+i) // page``, offset
+    ``(pos+i) % page`` within the row's mapped physical page. Tokens at
+    i >= n_valid[b] (and any position whose page is unmapped) are routed
+    to the layer's trash page. The engine guarantees every valid
+    position's page is mapped before the step runs. Its operations carry
+    the name scope ``kv_insert`` in the compiled program.
     """
     with jax.named_scope("kv_insert"):
-        return _paged_insert(cache, k_new, v_new, page_table, pos, n_valid)
+        return _paged_insert(cache, k_new, v_new, page_table, pos, n_valid,
+                             layer)
 
 
-def _paged_insert(cache, k_new, v_new, page_table, pos, n_valid):
-    P1, _, page = cache["kp"].shape[:3]
-    B, C, K, hd = k_new.shape
+def _paged_insert(cache, k_new, v_new, page_table, pos, n_valid, layer):
+    """One scatter per pool leaf into the stack at ``(layer, phys, k,
+    ...)``: a token's ``hd`` values (or its scale) for one KV head. It
+    updates the stack in place, in the layout the stack has: no
+    relayout, and no slice of the layer's pool out of the stack."""
+    B, C, _, hd = k_new.shape
+    P1, K = cache["kp"].shape[1:3]
+    page, store_hd = ops.paged_pool_shape(
+        cache["kp"], cache.get("kp_scale"), hd)
     npg = page_table.shape[1]
     logical = (jnp.asarray(pos, jnp.int32).reshape(B, 1)
                + jnp.arange(C, dtype=jnp.int32)[None, :])      # (B, C)
@@ -400,28 +414,36 @@ def _paged_insert(cache, k_new, v_new, page_table, pos, n_valid):
     ok = (jnp.arange(C, dtype=jnp.int32)[None, :]
           < jnp.asarray(n_valid, jnp.int32).reshape(B, 1))
     ok &= (phys >= 0) & (pg < npg)
-    row = jnp.where(ok, phys, P1 - 1).reshape(B * C)          # trash page
-    off = off.reshape(B * C)
+    phys = jnp.where(ok, phys, P1 - 1).reshape(B * C, 1)        # trash page
+    off = off.reshape(B * C, 1)
+    kh = jnp.arange(K, dtype=jnp.int32)[None, :]
+    lyr = jnp.asarray(layer, jnp.int32)
 
-    def put(pool, new):  # pool (P1, K, page, ...), new (B, C, K, ...)
-        return pool.at[row, :, off].set(
-            new.reshape((B * C,) + new.shape[2:]).astype(pool.dtype))
+    def put(pool, new):  # new (B * C, K, ...)
+        new = new.astype(pool.dtype)
+        t = page // pool.shape[3]  # tokens to a row; 1 for the scales
+        if t == 1:
+            return pool.at[lyr, phys, kh, off].set(new)
+        # the token's lanes of its row
+        w = new.shape[-1]
+        lanes = (off % t * w)[..., None] + jnp.arange(w, dtype=jnp.int32)
+        return pool.at[lyr, phys[..., None], kh[..., None],
+                       (off // t)[..., None], lanes].set(new)
 
     out = dict(cache)
     if "kp_scale" in cache:
-        store_hd = cache["kp"].shape[-1]
         qz = quant.quantize_int4 if store_hd != hd else quant.quantize_int8
         kq, ks = qz(k_new.reshape(B * C, K, hd))
         vq, vs = qz(v_new.reshape(B * C, K, hd))
-        out["kp"] = put(cache["kp"], kq.reshape(B, C, K, store_hd))
-        out["vp"] = put(cache["vp"], vq.reshape(B, C, K, store_hd))
-        out["kp_scale"] = put(cache["kp_scale"], ks.reshape(B, C, K))
-        out["vp_scale"] = put(cache["vp_scale"], vs.reshape(B, C, K))
+        out["kp"] = put(cache["kp"], kq)
+        out["vp"] = put(cache["vp"], vq)
+        out["kp_scale"] = put(cache["kp_scale"], ks)
+        out["vp_scale"] = put(cache["vp_scale"], vs)
     else:
         _check_insert_dtype(cache["kp"].dtype, k_new.dtype,
                             "paged_cache_insert")
-        out["kp"] = put(cache["kp"], k_new)
-        out["vp"] = put(cache["vp"], v_new)
+        out["kp"] = put(cache["kp"], k_new.reshape(B * C, K, hd))
+        out["vp"] = put(cache["vp"], v_new.reshape(B * C, K, hd))
     return out
 
 
@@ -442,16 +464,18 @@ def paged_copy_pages(cache, src, dst):
 
 
 def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
-                           pos, n_valid, *, window=None, rope=True):
+                           pos, n_valid, *, layer, window=None, rope=True):
     """C-token attention against the paged pool; returns (out, new_cache).
 
     x: (B, C, d) — the chunk program's mixed batch: decode rows feed one
     real token, chunked-prefill rows up to C (``n_valid`` masks the
-    rest). The new K/V are scattered into the rows' pages first, then
-    every query attends causally over exactly its row's occupied pages
-    (``ops.paged_attention``). Positions beyond ``n_valid`` produce
-    garbage the caller masks at the logit gather. ``window`` is the
-    layer's own; rope False for a layer without rotary embedding.
+    rest). ``cache`` is the pool stacked over layers, as in
+    ``paged_cache_insert``. The new K/V are written into the rows' pages
+    of ``layer`` first, then every query attends causally over exactly
+    its row's occupied pages of that layer (``ops.paged_attention``).
+    Positions beyond ``n_valid`` produce garbage the caller masks at the
+    logit gather. ``window`` is the layer's own; rope False for a layer
+    without rotary embedding.
     """
     B, C, _ = x.shape
     q = _qkv(params, x, cfg, "q")
@@ -465,13 +489,13 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
             posm = jnp.broadcast_to(posm[..., None], (B, C, 3))
     q, k_new = _qk(params, q, k_new, cfg, posm, rope)
     new_cache = paged_cache_insert(
-        cache, k_new, v_new, page_table, pos, n_valid)
+        cache, k_new, v_new, page_table, pos, n_valid, layer)
     with jax.named_scope("attention"):
         out = ops.paged_attention(
             q, new_cache["kp"], new_cache["vp"], page_table,
             pos=pos, n_valid=n_valid, window=window,
             kp_scale=new_cache.get("kp_scale"),
-            vp_scale=new_cache.get("vp_scale"),
+            vp_scale=new_cache.get("vp_scale"), layer=layer,
         )
     return _out_proj(params, x, out, cfg), new_cache
 

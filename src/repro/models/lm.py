@@ -398,15 +398,16 @@ def decode_chunk(params, cfg: ModelConfig, tokens, cache, page_table, pos,
     x = _embed(vals, cfg, tokens)
     x = constrain(x, "batch", None, None)
 
-    def block_fn(x, binp):
-        bparams, bcache = binp
+    def block_fn(carry, binp):
+        x, cache = carry
+        bparams, layer = binp
         new_entries, routed = [], []
         for j, spec in enumerate(cfg.block_pattern):
             lp = bparams[j]
             h = L.apply_norm(lp["norm1"], x, cfg)
             y, nc = L.attention_decode_paged(
-                lp["mixer"], h, cfg, bcache[j], page_table, pos, n_valid,
-                window=_window(spec, window), rope=spec.rope)
+                lp["mixer"], h, cfg, cache[j], page_table, pos, n_valid,
+                layer=layer, window=_window(spec, window), rope=spec.rope)
             new_entries.append(nc)
             x = x + _post(lp, "post_norm1", y, cfg)
             if spec.ffn != "none":
@@ -416,11 +417,15 @@ def decode_chunk(params, cfg: ModelConfig, tokens, cache, page_table, pos,
                 if r is not None:
                     routed.append(r)
                 x = x + _post(lp, "post_norm2", y, cfg)
-        return x, (tuple(new_entries),
-                   (jnp.stack(routed),) if routed else ())
+        return (x, tuple(new_entries)), ((jnp.stack(routed),) if routed
+                                         else ())
 
-    x, (new_cache, routed) = jax.lax.scan(block_fn, x,
-                                          (vals["blocks"], cache))
+    # The stacked pools ride the carry: each layer writes its rows in
+    # place and attends over the whole stack, so no layer's pool is ever
+    # sliced out or copied back.
+    (x, new_cache), routed = jax.lax.scan(
+        block_fn, (x, cache),
+        (vals["blocks"], jnp.arange(cfg.n_blocks, dtype=jnp.int32)))
     x = L.apply_norm(vals["final_norm"], x, cfg)
     if full_logits:
         return (_head(vals, cfg, x), new_cache) + routed

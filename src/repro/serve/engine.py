@@ -5,10 +5,14 @@ Two KV layouts share one engine surface (submit/step/run):
 **paged** (default for attention-only stacks): KV lives in a shared
 :class:`repro.serve.cache.PagePool`; a **single compiled program** — the
 chunk step from ``train.steps.make_serve_chunk_step`` — advances every
-slot each round. Decode rows feed one token; admitted prompts are fed as
-fixed-size **chunked-prefill** slices of the same (B, C) batch, so a
-prompt of any length maps onto the one compile shape: there are zero
-per-prompt-length prefill specializations (asserted via the jit
+slot each round. It is donated the KV pool and writes the fed tokens
+into it in place, so one pool set lives on the device; the engine's
+other pool writers (copy-on-write, defrag, the enc-dec cross insert)
+rebind ``_cache`` before the next step. Decode rows feed one token;
+admitted prompts are fed as fixed-size **chunked-prefill** slices of
+the same (B, C) batch, so a prompt of any length maps onto the one
+compile shape: there are zero per-prompt-length prefill
+specializations (asserted via the jit
 cache-miss counter in tests/test_serve.py). Admission is by free-page
 budget (``PagedScheduler``); when decode growth exhausts the pool the
 engine preempts youngest-first — the victim re-queues at the FIFO front
@@ -213,9 +217,13 @@ class Engine:
         if layout == "paged":
             # With a drafter the one chunk program returns the head over
             # all C positions (verify needs every draft's logits) — a
-            # different jit, but still exactly one compiled program.
+            # different jit, but still exactly one compiled program. It
+            # is donated the KV pool and updates it in place: every
+            # holder of ``self._cache`` rebinds it to the program's
+            # output before the next step.
             self._chunk_jit = jax.jit(make_serve_chunk_step(
-                cfg, rules, full_logits=self._drafter is not None))
+                cfg, rules, full_logits=self._drafter is not None),
+                donate_argnums=(2,))
             if cfg.is_encdec:
                 api = self.api
 

@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
@@ -100,75 +101,75 @@ def test_cache_from_prefill_matches_inserts():
 # --------------------------------------------------------------------------- #
 # Paged pool insert (serving; see repro.serve.cache.PagePool).
 # --------------------------------------------------------------------------- #
-def test_paged_cache_insert_lands_in_mapped_pages():
-    cfg = _cfg()
-    K, hd = cfg.n_kv_heads, cfg.head_dim
-    page, n_pages = 4, 6
-    cache = L.init_paged_kv_cache(cfg, n_pages, page)
-    assert cache["kp"].shape == (n_pages + 1, K, page, hd)  # + trash page
-    pt = jnp.asarray([[2, 5, -1], [4, -1, -1]], jnp.int32)
-    B, C = 2, 3
-    k = jax.random.normal(KEY, (B, C, K, hd))
-    # row 0 writes positions 3..5 (page 0 tail + page 1 head); row 1
-    # writes position 1 only (n_valid=1)
-    out = L.paged_cache_insert(
-        cache, k, k, pt, jnp.asarray([3, 1], jnp.int32),
-        jnp.asarray([3, 1], jnp.int32))
-    kp = np.asarray(out["kp"], np.float32)
-    kf = np.asarray(k, np.float32)
-    np.testing.assert_allclose(kp[2, :, 3], kf[0, 0], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[5, :, 0], kf[0, 1], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[5, :, 1], kf[0, 2], rtol=1e-2, atol=1e-2)
-    np.testing.assert_allclose(kp[4, :, 1], kf[1, 0], rtol=1e-2, atol=1e-2)
-    # row 1's masked tokens went to the trash page, not a real one
-    assert np.abs(kp[:n_pages]).astype(bool).sum() == 4 * K * hd
-
-
-def test_paged_cache_insert_int8_roundtrip():
-    cfg = _cfg("int8")
-    K, hd = cfg.n_kv_heads, cfg.head_dim
-    page, n_pages = 4, 3
-    cache = L.init_paged_kv_cache(cfg, n_pages, page)
-    assert cache["kp"].dtype == jnp.int8
-    pt = jnp.asarray([[1, 0]], jnp.int32)
-    k = jax.random.normal(KEY, (1, 4, K, hd)) * 3.0
-    out = L.paged_cache_insert(
-        cache, k, k, pt, jnp.asarray([2], jnp.int32),
-        jnp.asarray([4], jnp.int32))
-    deq = (np.asarray(out["kp"], np.float32)
-           * np.asarray(out["kp_scale"])[..., None])
-    # positions 2..5 -> page1[2], page1[3], page0[0], page0[1]
-    for i, (phys, off) in enumerate(((1, 2), (1, 3), (0, 0), (0, 1))):
-        err = np.abs(deq[phys, :, off] - np.asarray(k)[0, i])
-        step = np.asarray(out["kp_scale"])[phys, :, off][..., None]
-        assert float((err - step).max()) < 1e-5
-
-
-# --------------------------------------------------------------------------- #
-# int4 pools + the silent-upcast bugfix.
-# --------------------------------------------------------------------------- #
-def test_paged_cache_insert_int4_roundtrip():
-    """int4 pools pack two head dims per byte (halves layout) with the
-    same per-(token, head) scales; dequantization reconstructs within
-    one quantization step."""
+@pytest.mark.parametrize("n_blocks,layer", [(1, 0), (3, 2)],
+                         ids=["one-layer", "stacked"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "int4"])
+def test_paged_cache_insert_roundtrip(kv_dtype, n_blocks, layer):
+    """Two rows' tokens land in their mapped pages of the written layer
+    and read back (bf16 to its rounding; int8 and int4, whose pools pack
+    two head dims per byte, within one quantization step); masked
+    positions go to that layer's trash page; every other row of the
+    pool, and every row of the stack's other layers, stays bit-identical.
+    ``one-layer`` is a stack of one layer, ``stacked`` layer 2 of a
+    three-layer stack as the chunk program carries it. The pools hold
+    several tokens to a row here (head_dim 16, int4-packed 8)."""
     from repro.kernels import quant
 
-    cfg = _cfg("int4")
+    cfg = _cfg(kv_dtype)
     K, hd = cfg.n_kv_heads, cfg.head_dim
-    page, n_pages = 4, 3
-    cache = L.init_paged_kv_cache(cfg, n_pages, page)
-    assert cache["kp"].shape == (n_pages + 1, K, page, hd // 2)
-    assert cache["kp"].dtype == jnp.int8  # packed nibbles
-    pt = jnp.asarray([[1, 0]], jnp.int32)
-    k = jax.random.normal(KEY, (1, 4, K, hd)) * 3.0
-    out = L.paged_cache_insert(
-        cache, k, k, pt, jnp.asarray([2], jnp.int32),
-        jnp.asarray([4], jnp.int32))
-    deq = np.asarray(quant.dequantize(out["kp"], out["kp_scale"], hd))
-    for i, (phys, off) in enumerate(((1, 2), (1, 3), (0, 0), (0, 1))):
-        err = np.abs(deq[phys, :, off] - np.asarray(k)[0, i])
-        step = np.asarray(out["kp_scale"])[phys, :, off][..., None]
-        assert float((err - step).max()) < 1e-5
+    page, n_pages = 4, 6
+    pool = L.init_paged_kv_cache(cfg, n_pages, page)
+    store_hd = hd // 2 if kv_dtype == "int4" else hd
+    t = ops.paged_tokens_per_row(page, store_hd)
+    assert t > 1
+    assert pool["kp"].shape == (n_pages + 1, K, page // t,
+                                t * store_hd)  # + trash
+    assert pool["kp"].dtype == (jnp.int8 if kv_dtype != "bfloat16"
+                                else jnp.bfloat16)
+    # every row starts non-zero, so that a stray write shows
+    keys = iter(jax.random.split(KEY, 8))
+    lead = (n_blocks,)
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jax.random.randint(next(keys), lead + a.shape, 1, 100,
+                                      a.dtype)
+        return jax.random.uniform(next(keys), lead + a.shape, a.dtype,
+                                  0.5, 1.5)
+
+    cache = {name: fill(a) for name, a in pool.items()}
+    pt = jnp.asarray([[2, 5, -1], [4, -1, -1]], jnp.int32)
+    k = jax.random.normal(next(keys), (2, 3, K, hd)) * 3.0
+    # row 0 writes positions 3..5 (page 0 tail + page 1 head); row 1
+    # writes position 1 only (n_valid 1), its masked tokens 1 and 2
+    # (positions 2, 3) go to the trash page
+    out = L.paged_cache_insert(cache, k, k, pt, jnp.asarray([3, 1], jnp.int32),
+                               jnp.asarray([3, 1], jnp.int32), layer)
+    writes = {(2, 3): (0, 0), (5, 0): (0, 1), (5, 1): (0, 2), (4, 1): (1, 0),
+              (n_pages, 2): (1, 1), (n_pages, 3): (1, 2)}
+    for name in cache:
+        before, after = np.asarray(cache[name]), np.asarray(out[name])
+        assert after.dtype == before.dtype and after.shape == before.shape
+        diff = before != after
+        diff = diff.reshape(diff.shape[:3] + (page, -1)).any(axis=(2, 4))
+        changed = {(int(b), int(p), int(o)) for b, p, o in zip(*diff.nonzero())}
+        assert changed <= {(layer, p, o) for p, o in writes}, name
+    kf = np.asarray(k, np.float32)
+    for name in ("kp", "vp"):
+        vals = np.asarray(out[name])[layer]
+        vals = vals.reshape(vals.shape[:2] + (page, store_hd))
+        if kv_dtype == "bfloat16":
+            deq, step = vals.astype(np.float32), None
+        else:
+            scale = np.asarray(out[name + "_scale"])[layer]
+            deq = np.asarray(quant.dequantize(vals, scale, hd))
+        for (phys, off), (b, i) in writes.items():
+            err = np.abs(deq[phys, :, off] - kf[b, i])
+            if kv_dtype == "bfloat16":
+                assert float((err - 1e-2 * np.abs(kf[b, i])).max()) < 1e-2
+            else:
+                step = scale[phys, :, off][..., None]
+                assert float((err - step).max()) < 1e-5
 
 
 def test_int4_slab_cache_rejected():
@@ -185,8 +186,6 @@ def test_insert_refuses_silent_upcast_into_integer_pool():
     """The old fallback path quietly did astype(int8) on float K/V when
     a quantized pool was missing its scale entries — garbage attention
     with no error. Now it raises at trace time."""
-    import pytest
-
     cfg = _cfg("int8")
     K, hd = cfg.n_kv_heads, cfg.head_dim
 
@@ -202,8 +201,8 @@ def test_insert_refuses_silent_upcast_into_integer_pool():
     # the intact quantized cache accepts the same write
     L.cache_insert(cache, knew, knew, 0)
 
-    # paged: same contract
-    pcache = L.init_paged_kv_cache(cfg, 2, 4)
+    # paged: same contract, on a stack of one layer
+    pcache = {k: v[None] for k, v in L.init_paged_kv_cache(cfg, 2, 4).items()}
     pbare = {k: v for k, v in pcache.items()
              if k not in ("kp_scale", "vp_scale")}
     pt = jnp.asarray([[0, 1]], jnp.int32)
@@ -211,7 +210,7 @@ def test_insert_refuses_silent_upcast_into_integer_pool():
     with pytest.raises(TypeError, match="quantization scales"):
         L.paged_cache_insert(pbare, kc, kc, pt,
                              jnp.asarray([0], jnp.int32),
-                             jnp.asarray([2], jnp.int32))
+                             jnp.asarray([2], jnp.int32), 0)
     L.paged_cache_insert(pcache, kc, kc, pt,
                          jnp.asarray([0], jnp.int32),
-                         jnp.asarray([2], jnp.int32))
+                         jnp.asarray([2], jnp.int32), 0)

@@ -369,6 +369,50 @@ def test_quantize_bounded_error(qz, lim):
                                atol=float(scale.max()) * 0.51 + 1e-6)
 
 
+@pytest.mark.parametrize("backend", ["kernel", "jnp"])
+@pytest.mark.parametrize("pool,D", [("bfloat16", 128), ("bfloat16", 64),
+                                    ("int8", 128), ("int4", 128)])
+def test_paged_attention_reads_one_layer_of_a_stack(pool, D, backend,
+                                                    monkeypatch):
+    """``ops.paged_attention(..., layer=l)`` on the chunk program's pools
+    stacked over three layers, stored as ``init_paged_kv_cache`` stores
+    them (two tokens to a row where a token is 64 lanes wide), reads layer
+    1 alone: equal to the oracle on that layer's own pool, through the
+    kernel (interpret mode; the stack as one pool at 128 lanes, the
+    layer's pool sliced out below) and through the jnp path."""
+    if backend == "kernel":
+        monkeypatch.setenv("REPRO_USE_PALLAS", "interpret")
+    lens, nvs = [40, 9, 3], [4, 1, 3]
+    n, layer, page = 3, 1, 16
+    cases = [_paged_case(7 + i, 3, 4, 4, 2, D, page, 10, 4, lens, nvs,
+                         jnp.float32) for i in range(n)]
+    q, _, _, pt, pos, nv = cases[layer]
+    kps = jnp.stack([c[1] for c in cases])
+    vps = jnp.stack([c[2] for c in cases])
+    scales = {}
+    if pool == "bfloat16":
+        kps, vps = kps.astype(jnp.bfloat16), vps.astype(jnp.bfloat16)
+    else:
+        qz = quant.quantize_int8 if pool == "int8" else quant.quantize_int4
+        kps, vps, ks, vs = _quantize_pool(kps, vps, qz)
+        scales = dict(kp_scale=ks, vp_scale=vs)
+    want = ref.paged_attention(
+        q, kps[layer], vps[layer], pt, pos=pos, n_valid=nv,
+        **{k: v[layer] for k, v in scales.items()})
+    hd = kps.shape[-1]
+    t = ops.paged_tokens_per_row(page, hd)
+    assert t == (2 if hd == 64 else 1)
+    rows = lambda a: a.reshape(a.shape[:3] + (page // t, t * hd))
+    got = ops.paged_attention(q, rows(kps), rows(vps), pt, pos=pos,
+                              n_valid=nv, layer=jnp.int32(layer), **scales)
+    tol = (_tol(jnp.bfloat16) if pool == "bfloat16"
+           else dict(rtol=2e-5, atol=2e-5))
+    for b, m in enumerate(nvs):
+        np.testing.assert_allclose(np.asarray(got[b, :m], np.float32),
+                                   np.asarray(want[b, :m], np.float32),
+                                   **tol)
+
+
 def _quantize_pool(kp, vp, qz):
     kq, ks = qz(kp)  # per-(page, head, token) scales over head_dim
     vq, vs = qz(vp)

@@ -2,7 +2,9 @@
 continuous-batching vs sequential decode equivalence, KV-slot reuse
 after retirement, and the paged layout — allocator invariants under
 randomized admit/retire/overflow/preempt sequences, paged==slab token
-identity, the one-compiled-program contract, and clean preemption."""
+identity, the one-compiled-program contract, clean preemption, and the
+chunk program's donated KV pool."""
+import dataclasses
 import random
 
 import jax
@@ -30,7 +32,7 @@ from repro.serve import (
     write_slot,
 )
 from repro.serve.engine import synthetic_requests
-from repro.train.steps import ModelAPI
+from repro.train.steps import ModelAPI, make_serve_chunk_step
 
 
 # --------------------------------------------------------------------------- #
@@ -433,6 +435,121 @@ def test_paged_preemption_and_defrag_keep_tokens_identical():
         eng.step()
     got2 = [r.tokens for r in sorted(eng._finished, key=lambda r: r.id)]
     assert got2 == want
+
+
+# --------------------------------------------------------------------------- #
+# The chunk program is donated the KV pool and updates it in place.
+# --------------------------------------------------------------------------- #
+def _afmoe_tiny():
+    """Two blocks of Trinity-Mini's first four layer kinds (dense and MoE,
+    windowed and global) at a CPU size, so layer 1 of each stack is
+    written too."""
+    from repro.configs import trinity_mini
+
+    base = trinity_mini.CONFIG
+    return dataclasses.replace(
+        base, name="trinity-tiny", n_layers=8,
+        block_pattern=trinity_mini.layers(4, window=4), d_model=64,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96, vocab=128,
+        param_sharding="replicated", remat=False,
+        moe=dataclasses.replace(base.moe, n_experts=8, top_k=2, d_expert=32))
+
+
+def _prompts(cfg, lens, new=6, seed=7, copies=1):
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(0, cfg.vocab, size=int(p)).tolist() for p in lens]
+    return [Request(prompt=list(p), max_new_tokens=new)
+            for p in prompts for _ in range(copies)]
+
+
+def _repeating(cfg, n=3, new=8, seed=3):
+    """Prompts that repeat a short phrase, so the n-gram drafter has a
+    suffix to continue."""
+    rng = np.random.RandomState(seed)
+    return [Request(prompt=rng.randint(0, cfg.vocab, size=3).tolist() * 4,
+                    max_new_tokens=new) for _ in range(n)]
+
+
+_GEMMA = lambda: get_config("gemma-7b").reduced()
+_DONATION_CASES = {
+    # staggered arrivals: prefill chunks and decode rows in one step
+    "mixed": (_GEMMA, dict(max_batch=3, max_len=32),
+              lambda cfg: _request_stream(cfg, seed=11), None),
+    # duplicate prompts behind one slot: full-prompt prefix hits that
+    # copy-on-write their last page
+    "prefix_cow": (_GEMMA, dict(max_batch=1, max_len=32, prefix_cache=True),
+                   lambda cfg: _prompts(cfg, (8,), new=5, seed=2, copies=3),
+                   None),
+    # a 6-page pool: preempted rows resume by re-prefilling
+    "preempt": (_GEMMA, dict(max_batch=4, max_len=32, n_pages=6),
+                lambda cfg: _prompts(cfg, (9, 7, 12, 5)), None),
+    # the pool compacted after step 5, mid-flight
+    "defrag": (_GEMMA, dict(max_batch=4, max_len=32),
+               lambda cfg: _prompts(cfg, (9, 7, 12, 5)), 5),
+    # window and global layers, dense and MoE, two layers per stack
+    "afmoe": (_afmoe_tiny, dict(max_batch=2, max_len=48, prefill_chunk=8),
+              lambda cfg: _prompts(cfg, (30, 9), new=4), None),
+    # n-gram drafts verified by the same program with the full head
+    "speculative": (_GEMMA, dict(max_batch=2, max_len=40, prefill_chunk=8,
+                                 spec_decode="ngram"),
+                    _repeating, None),
+    # enc-dec: the cross K/V written per admission beside the self pools
+    "encdec": (lambda: get_config("whisper-medium").reduced(),
+               dict(max_batch=2, max_len=32),
+               lambda cfg: synthetic_requests(cfg, n=4, tokens=4,
+                                              prompt_len=10,
+                                              scenario="server", seed=5),
+               None),
+}
+
+
+@pytest.mark.parametrize("case", list(_DONATION_CASES))
+def test_donated_pool_serves_undonated_tokens(case):
+    """The engine's chunk program is donated the KV pool. Copy-on-write,
+    preemption, defrag, speculative verify, the enc-dec cross insert and
+    every layer kind rebind the pool before the next step, so no donated
+    buffer is read again (a read would raise), and greedy tokens equal
+    those of the same engine whose program is compiled without
+    donation."""
+    make_cfg, kw, workload, defrag_at = _DONATION_CASES[case]
+    cfg = make_cfg()
+    api = ModelAPI(cfg)
+    params, _ = split_tree(api.init(cfg, jax.random.PRNGKey(0)))
+    scfg = ServeConfig(kv_layout="paged", page_size=4,
+                       **{"prefill_chunk": 4, **kw})
+
+    def serve(donated: bool):
+        eng = Engine(cfg, params, None, scfg)
+        if not donated:
+            eng._chunk_jit = jax.jit(make_serve_chunk_step(
+                cfg, full_logits=eng._drafter is not None))
+        first = next(a for path, a in jax.tree_util.tree_leaves_with_path(
+            eng._cache) if "kp" in jax.tree_util.keystr(path))  # a pool
+        for r in workload(cfg):
+            eng.submit(r)
+        n = 0
+        while eng._arrivals or eng.sched.has_work:
+            eng.step()
+            n += 1
+            if n == defrag_at:
+                eng.defrag()
+        assert first.is_deleted() == donated
+        return eng, [r.tokens for r in sorted(eng._finished,
+                                              key=lambda r: r.id)]
+
+    eng, got = serve(donated=True)
+    _, want = serve(donated=False)
+    assert len(got) == len(workload(cfg)) and got == want
+    assert eng.compiled_programs() == (
+        {"chunk": 1, "encode": 1} if cfg.is_encdec else {"chunk": 1})
+    if case == "prefix_cow":
+        assert eng._cow == 2
+    if case == "preempt":
+        assert eng._preempted > 0
+    if case == "mixed":
+        assert {"mixed", "decode"} <= {s.kind for s in eng._trace}
+    if case == "speculative":
+        assert eng._draft_total > 0
 
 
 @pytest.mark.slow

@@ -6,14 +6,19 @@ array's own, VMEM overruns, a kernel XLA would have to partition. These
 tests compile, for a described ``v5e:2x2`` topology with no chip
 attached, the paged decode kernel at Yi-9B serving shapes and the flash
 kernel's forward and gradient at Yi-9B training shapes, on one chip and
-under a 2x2 mesh. Nothing runs; they guard the layouts at no chip time.
+under a 2x2 mesh, and the serving chunk program, which must update its
+donated KV pool in place. Nothing runs; they guard the layouts at no
+chip time.
 
 The topology is described inside a module fixture (only one process at a
 time may load the TPU library, so never at import), which skips where it
 cannot be described. The persistent compile cache is off around these
 compiles: an entry written for a described chip cannot be read back.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -183,3 +188,109 @@ def test_moe_gmm_compiles_for_v5e(one_chip, k, n):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all("%moe_gmm" in line.split(" = ")[0]
                          for line in calls), calls[:1]
+
+
+# One HLO instruction: its name, result shape (arrays only) and opcode.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _chunk_program(device, cfg, B, C, page, n_pages, max_pages):
+    """The serving chunk program compiled for ``device``, donated its
+    cache as the engine donates it: (HLO text, the cache's leaves, every
+    instruction as (name, result dims, opcode), the opcode ``scatter``
+    for a fusion whose root is a scatter). The insert is the program's
+    only scatter into the pools."""
+    from repro.dist import split_tree
+    from repro.train import steps as T
+
+    api = T.ModelAPI(cfg)
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=device)
+    params = jax.tree.map(S, jax.eval_shape(
+        lambda: split_tree(api.init(cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree.map(S, jax.eval_shape(
+        lambda: api.init_paged_cache(B, n_pages, page)))
+    i32 = lambda *s: S(jax.ShapeDtypeStruct(s, jnp.int32))
+    step = jax.jit(T.make_serve_chunk_step(cfg), donate_argnums=(2,))
+    text = step.lower(params, i32(B, C), cache, i32(B, max_pages), i32(B),
+                      i32(B)).compile().as_text()
+    roots = dict(re.findall(
+        r"^%?(\S+) .*\{\n(?:.*\n)*?\s*ROOT %\S+ = \S+ (\w+)\(", text, re.M))
+    instrs = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m or not m.group(2):
+            continue
+        name, op = m.group(1), m.group(3)
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.-]+)", line).group(1)
+            op = "scatter" if roots.get(called) == "scatter" else op
+        dims = tuple(int(d) for d in m.group(2).split(","))
+        instrs.append((name, dims, op))
+    return text, jax.tree.leaves(cache), instrs
+
+
+def _aliased(text):
+    module = text.splitlines()[0]  # HloModule ..., input_output_alias=...
+    return len(re.findall(r"\{\d+\}: \(\d+, \{\}, \w+-alias\)", module))
+
+
+def test_chunk_program_updates_donated_pool_in_place(one_chip, monkeypatch):
+    """The serving chunk program at Yi-9B widths (two layers, 32 rows of
+    C = 16, a pool of 5120 pages and the trash page), donated its cache
+    as the engine donates it: the pools are aliased from input to
+    output, and no instruction but the insert's scatters and bitcasts
+    has a result the size of a layer's pool or a multiple of it: no
+    copy, transpose, dynamic slice or dynamic update of the pool."""
+    from repro.configs import get_config
+
+    monkeypatch.setenv("REPRO_USE_PALLAS", "tpu")  # CPU backend here
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=2)
+    n_pages = 5120
+    text, leaves, instrs = _chunk_program(one_chip, cfg, 32, 16, 16,
+                                          n_pages, 160)
+    assert [a.shape for a in leaves] == [(2, n_pages + 1, K, PAGE, D)] * 2
+    assert _aliased(text) == len(leaves), text.splitlines()[0][:300]
+    pool = math.prod(leaves[0].shape[1:])
+    sized = [(name, op) for name, dims, op in instrs
+             if math.prod(dims) % pool == 0]
+    assert any(op == "scatter" for _, op in sized), (
+        "no scatter of the insert in the program")
+    pooled = [f"{name} {op}" for name, op in sized if op not in (
+        "scatter", "parameter", "get-tuple-element", "bitcast")]
+    assert not pooled, pooled
+
+
+@pytest.mark.parametrize("kv", ["bfloat16", "int4"],
+                         ids=["head_dim64", "int4"])
+def test_narrow_pool_chunk_program_copies_one_layer_at_most(
+        one_chip, monkeypatch, kv):
+    """Pools narrower than 128 lanes (head_dim 64, and Yi-9B's 128 dims
+    packed to 64 int4 bytes) hold two tokens to a 128-lane row, so the
+    stack keeps the standard layout. Eight layers at the chat cell's
+    shapes, donated: the pools are aliased, and no instruction but the
+    insert's scatters has a result larger than one layer's pool padded
+    to the lanes; the kernel takes each layer's pool, sliced out and
+    padded, one layer at a time."""
+    from repro.configs import get_config
+
+    monkeypatch.setenv("REPRO_USE_PALLAS", "tpu")  # CPU backend here
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=8,
+                              kv_cache_dtype=kv)
+    if kv == "bfloat16":
+        cfg = dataclasses.replace(cfg, head_dim=64)
+    n_pages = 5120
+    text, leaves, instrs = _chunk_program(one_chip, cfg, 32, 16, 16,
+                                          n_pages, 160)
+    pools = [a for a in leaves if a.ndim == 5]
+    assert [a.shape for a in pools] == [(8, n_pages + 1, K, PAGE // 2,
+                                         128)] * 2
+    assert _aliased(text) == len(leaves), text.splitlines()[0][:300]
+    layer = math.prod(pools[0].shape[1:])
+    sized = [(name, op, dims) for name, dims, op in instrs
+             if math.prod(dims) > 2 * layer and (
+                 n_pages + 1 in dims or math.prod(dims) % layer == 0)]
+    assert any(op == "scatter" for _, op, _ in sized), (
+        "no scatter of the insert in the program")
+    big = [f"{name} {op} {dims}" for name, op, dims in sized if op not in (
+        "scatter", "parameter", "get-tuple-element", "bitcast")]
+    assert not big, big
