@@ -5,8 +5,7 @@ registers an :class:`~repro.kernels.dispatch.OpSpec` naming its pure-JAX
 implementation, its (lazily imported) Pallas kernel, and capability
 flags — ``supports_int8``/``supports_int4`` for quantized operands,
 ``min_size`` for launch-overhead gates. The public functions here are
-thin shims that keep the historical call signatures and route through
-``dispatch.resolve``.
+thin shims that route through ``dispatch.resolve``.
 
 On TPU the Pallas kernels are used; on CPU (this container) the
 memory-safe pure-JAX implementations are used for model execution and
@@ -19,13 +18,13 @@ used by kernel integration tests).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import dispatch as _dispatch
+from repro.kernels import paged_pool as _paged_pool
 from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
 
@@ -234,41 +233,31 @@ def _decode_attention_jnp(q, k_cache, v_cache, slot_pos, *, pos, window,
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
-def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
-                    scale=None, kp_scale=None, vp_scale=None, layer=None):
-    """Ragged decode attention against a paged KV pool.
+def paged_attention(q, pool, page_table, *, pos, n_valid, layer,
+                    window=None, scale=None):
+    """Ragged decode attention against layer ``layer`` of a paged KV pool.
 
     q: (B, C, H, D) — C tokens per row this step (decode rows feed 1,
     chunked-prefill rows up to C; ``n_valid`` masks the rest).
-    kp/vp: (P, K, page, hd) physical page pool, head-major within a
-    page — bf16, int8 (hd == D) or int4-packed (hd == D // 2); the new
-    tokens' K/V are already written into their pages
-    (``layers.paged_cache_insert`` runs before attention). With
-    ``layer``, kp/vp (and the scales) are the chunk program's pools
-    stacked over layers, ``(n_blocks, P, K, page // t, t * hd)`` with
-    ``t`` tokens to a row (``layers.init_paged_kv_cache``), and layer
-    ``layer``'s pages are read (see ``_layer_pool``).
-    page_table: (B, max_pages) int32 physical page ids (-1 unmapped).
-    pos: (B,) absolute position of each row's first token this step.
-    kp_scale/vp_scale: (P, K, page) dequant scales for quantized pools;
-    both the Pallas kernel (dequant-in-kernel, fp32 accumulation) and
-    the jnp fallback consume them.
+    pool: the chunk program's pool stacked over layers, bf16, int8 or
+    int4-packed (``kernels/paged_pool.py``); the new tokens' K/V are
+    already written into their pages (``paged_pool.insert`` runs before
+    attention). page_table: (B, max_pages) int32 physical page ids (-1
+    unmapped). pos: (B,) absolute position of each row's first token
+    this step.
 
     On TPU (or REPRO_USE_PALLAS=interpret) the Pallas kernel visits only
-    the pages each row occupies; the jnp fallback gathers the mapped
-    pages and masks — O(max_len) per row, correctness-equal.
+    the pages each row occupies, dequantizing in-kernel with fp32
+    accumulation; the jnp fallback gathers the mapped pages and masks —
+    O(max_len) per row, correctness-equal. Both read the layer's pages
+    as ``paged_pool.layer_view`` hands them.
     """
     D = q.shape[-1]
-    page, hd = paged_pool_shape(kp, kp_scale, D)
-    if kp_scale is not None:
-        quantized = "int4" if hd != D else "int8"
-    else:
-        quantized = ""
+    quantized = _paged_pool.geometry(pool, D)[3]
     impl, interpret = _dispatch.resolve("paged_attention", quantized=quantized)
-    if layer is not None:
-        kp, vp, kp_scale, vp_scale, page_table = _layer_pool(
-            (kp, vp, kp_scale, vp_scale), page_table, layer, page, hd,
-            kernel=interpret is not None)
+    kp, vp, kp_scale, vp_scale, page_table = _paged_pool.layer_view(
+        pool, page_table, layer, D, kernel=interpret is not None,
+        sharded=_sharded())
     if interpret is None:
         return impl(q, kp, vp, page_table, pos=pos, n_valid=n_valid,
                     window=window, scale=scale, kp_scale=kp_scale,
@@ -280,72 +269,13 @@ def paged_attention(q, kp, vp, page_table, *, pos, n_valid, window=None,
                     window=window, scale=scale, kp_scale=ks, vp_scale=vs,
                     interpret=interpret)
 
-    pool = (None, "act_heads", None, None)
+    pages = (None, "act_heads", None, None)
     args = (q, kp, vp, page_table, pos, n_valid)
-    names = (_Q_AXES, pool, pool, ("batch", None), ("batch",), ("batch",))
+    names = (_Q_AXES, pages, pages, ("batch", None), ("batch",), ("batch",))
     if kp_scale is not None:
         args += (kp_scale, vp_scale)
-        names += (pool[:3], pool[:3])
+        names += (pages[:3], pages[:3])
     return _per_shard(fn, args, names)
-
-
-def paged_tokens_per_row(page: int, hd: int) -> int:
-    """Tokens of a page that share one pool row of ``hd``-wide tokens.
-
-    A TPU lays an array out in rows of 128 lanes. A pool whose rows are
-    narrower (head_dim 64, or an int4-packed 128) would be laid out with
-    its page axis minor, so every access by page, the insert's and the
-    kernel's, would relayout the whole pool. Rows of ``t`` tokens span
-    whole lanes where the page size allows (``t * hd`` a multiple of
-    128) and keep the standard layout; ``t`` is 1 for a pool 128 lanes
-    wide or wider.
-    """
-    from repro.kernels.paged_attention import LANES
-
-    return math.gcd(page, LANES // math.gcd(hd, LANES))
-
-
-def paged_pool_shape(kp, kp_scale, D):
-    """``(page, hd)`` of a pool whose pages are stored ``(..., page // t,
-    t * hd)``, ``t`` tokens to a row (``paged_tokens_per_row``):
-    a quantized pool's scales give the page, a bf16 pool's tokens are
-    the queries' D wide."""
-    rows = kp.shape[-2] * kp.shape[-1]
-    if kp_scale is not None:
-        return kp_scale.shape[-1], rows // kp_scale.shape[-1]
-    return rows // D, D
-
-
-def _layer_pool(pools, page_table, layer, page, hd, *, kernel):
-    """Layer ``layer`` of stacked pools ``(n_blocks, P, K, page // t,
-    t * hd)``, as one ``(pages, K, page, hd)`` pool and its page table.
-
-    The stack is read as one pool of ``n_blocks * P`` pages, the layer's
-    page ids offset by ``layer * P``: merging the leading dims is a
-    bitcast, so nothing is copied (a pool the kernel reads has one token
-    a row; the jnp path's rows of ``t`` tokens are reshaped). Two kernel
-    calls take the layer's own pool instead, sliced out (one layer's
-    copy), because the kernel copies what it is handed: a pool narrower
-    than its lanes, which it pads, and a call under a mesh, where each
-    device takes its heads' share of the operand.
-    """
-    from repro.kernels.paged_attention import LANES
-
-    sliced = kernel and (hd % LANES or _sharded())
-    lyr = jnp.asarray(layer, jnp.int32)
-
-    def one(a, kv):
-        if a is None:
-            return None
-        a = (jax.lax.dynamic_index_in_dim(a, lyr, keepdims=False) if sliced
-             else a.reshape((-1,) + a.shape[2:]))
-        # (..., page // t, t * hd) -> (..., page, hd); scales are per token
-        return a.reshape(a.shape[:-2] + (page, hd)) if kv else a
-
-    pt = jnp.asarray(page_table, jnp.int32)
-    if not sliced:
-        pt = jnp.where(pt >= 0, pt + lyr * pools[0].shape[1], -1)
-    return tuple(one(a, i < 2) for i, a in enumerate(pools)) + (pt,)
 
 
 def _paged_attention_jnp(q, kp, vp, page_table, *, pos, n_valid, window,

@@ -44,11 +44,11 @@ of q's and the pool's dtype (integer codes are exact in q's) and
 accumulate in fp32; P·V is fp32.
 
 The new tokens' K/V must already be written into their pages: the model
-layer scatters them in place before attending (``layers.paged_cache_insert``).
+layer scatters them in place before attending (``paged_pool.insert``).
 The chunk program hands the kernel its layer stack's pools as one pool,
 the layer's page ids offset into it, so no layer's pool is copied for the
 call; a pool narrower than the lanes, and a call under a mesh, take the
-layer's own pool instead (``ops._layer_pool``).
+layer's own pool instead (``paged_pool.layer_view``).
 Validated against ``kernels/ref.paged_attention`` in interpret mode on
 CPU (tests/test_kernels.py); ``tests/test_tpu_compile.py`` compiles it
 for a v5e.

@@ -1,5 +1,5 @@
-"""KV-cache quantization helpers shared by the model layers, the kernel
-fallbacks and the test oracles.
+"""KV-cache quantization helpers shared by the model layers, the paged
+pool, the kernel fallbacks and the test oracles.
 
 Symmetric per-row scales over the trailing (head) dimension:
 
@@ -62,3 +62,19 @@ def dequantize(pool, scale, head_dim: int):
     from the trailing-axis size."""
     vals = pool if pool.shape[-1] == head_dim else unpack_int4(pool)
     return vals.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
+
+
+def check_insert_dtype(pool_dtype, new_dtype, where: str) -> None:
+    """Writes into an integer pool must come through the quantizer.
+
+    Without this, the fallback ``astype(pool.dtype)`` would silently
+    truncate float K/V into an int8/int4 pool whose scale entries are
+    missing — garbage attention, no error. Dtypes are static, so this
+    raises at trace time, not mid-step.
+    """
+    if (jnp.issubdtype(pool_dtype, jnp.integer)
+            and not jnp.issubdtype(new_dtype, jnp.integer)):
+        raise TypeError(
+            f"{where}: writing {new_dtype} values into a {pool_dtype} pool "
+            "without quantization scales — quantized caches must carry "
+            "k_scale/v_scale (slab) or kp_scale/vp_scale (paged) entries")

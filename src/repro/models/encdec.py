@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist import constrain, p, retag_tree, split_tree, stack_axes
+from repro.kernels import paged_pool
 from repro.models import layers as L
 from repro.models.lm import _is_tagged_tree
 
@@ -197,10 +198,8 @@ def init_paged_cache(cfg: ModelConfig, B: int, n_pages: int, page: int):
     ``(n_layers, B, enc_source_len, ...)`` slab — it never grows, so
     paging buys nothing there.
     """
-    self_c = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape),
-        L.init_paged_kv_cache(cfg, n_pages, page),
-    )
+    self_c = paged_pool.init(cfg.n_layers, n_pages, page, cfg.n_kv_heads,
+                             cfg.head_dim, cfg.kv_cache_dtype, cfg.dtype)
     cross_c = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape),
         L.init_kv_cache(cfg, B, cfg.enc_source_len),

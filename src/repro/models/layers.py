@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import MambaConfig, ModelConfig, RWKV6Config
 from repro.dist import constrain, p
-from repro.kernels import ops, quant
+from repro.kernels import ops, paged_pool, quant
 
 
 def _cdtype(cfg: ModelConfig):
@@ -262,22 +262,6 @@ def init_kv_cache(cfg: ModelConfig, B: int, length: int) -> Dict[str, Any]:
 _quantize_kv = quant.quantize_int8
 
 
-def _check_insert_dtype(pool_dtype, new_dtype, where: str) -> None:
-    """Writes into an integer pool must come through the quantizer.
-
-    Without this, the fallback ``astype(pool.dtype)`` would silently
-    truncate float K/V into an int8/int4 pool whose scale entries are
-    missing — garbage attention, no error. Dtypes are static, so this
-    raises at trace time, not mid-step.
-    """
-    if (jnp.issubdtype(pool_dtype, jnp.integer)
-            and not jnp.issubdtype(new_dtype, jnp.integer)):
-        raise TypeError(
-            f"{where}: writing {new_dtype} values into a {pool_dtype} pool "
-            "without quantization scales — quantized caches must carry "
-            "k_scale/v_scale (slab) or kp_scale/vp_scale (paged) entries")
-
-
 def cache_insert(cache, k_new, v_new, pos):
     """Insert one token's K/V at ring slot pos % L. k_new/v_new: (B,K,hd).
 
@@ -303,7 +287,7 @@ def cache_insert(cache, k_new, v_new, pos):
         out["v_scale"] = jax.lax.dynamic_update_slice_in_dim(
             cache["v_scale"], vs[:, None], slot, axis=1)
     else:
-        _check_insert_dtype(cache["k"].dtype, k_new.dtype, "cache_insert")
+        quant.check_insert_dtype(cache["k"].dtype, k_new.dtype, "cache_insert")
         out["k"] = jax.lax.dynamic_update_slice_in_dim(
             cache["k"], k_new[:, None].astype(cache["k"].dtype), slot, axis=1)
         out["v"] = jax.lax.dynamic_update_slice_in_dim(
@@ -332,135 +316,10 @@ def _cache_insert_per_row(cache, k_new, v_new, posv):
         out["k_scale"] = put(cache["k_scale"], ks)
         out["v_scale"] = put(cache["v_scale"], vs)
     else:
-        _check_insert_dtype(cache["k"].dtype, k_new.dtype, "cache_insert")
+        quant.check_insert_dtype(cache["k"].dtype, k_new.dtype, "cache_insert")
         out["k"], out["v"] = put(cache["k"], k_new), put(cache["v"], v_new)
     out["slot_pos"] = jnp.where(hit, posv[:, None], cache["slot_pos"])
     return out
-
-
-# ---- paged KV cache (serving; see repro.serve.cache.PagePool) ------------- #
-def init_paged_kv_cache(cfg: ModelConfig, n_pages: int, page: int):
-    """Physical page pool for one attention layer.
-
-    ``n_pages`` real pages plus one trailing *trash* page (index
-    ``n_pages``) that absorbs masked writes — ``paged_cache_insert``
-    routes invalid token positions there so the scatter needs no
-    conditional. Validity is carried by the page table (-1 = unmapped)
-    plus per-row lengths, not by a per-slot ``slot_pos`` map.
-
-    Pages are head-major, ``(n_pages + 1, K, page // t, t * hd)`` (scales
-    ``(n_pages + 1, K, page)``): one KV head's page is ``page`` tokens of
-    ``hd`` values, ``t`` consecutive tokens to a row
-    (``ops.paged_tokens_per_row``), so the page read as ``(page, hd)`` is
-    the block the paged kernel takes.
-    """
-    K, hd = cfg.n_kv_heads, cfg.head_dim
-    quantized = cfg.kv_cache_dtype in ("int8", "int4")
-    store_hd = hd
-    if cfg.kv_cache_dtype == "int4":
-        if hd % 2:
-            raise ValueError(
-                f"int4 KV packs two dims per byte; head_dim {hd} is odd")
-        store_hd = hd // 2  # two nibbles per byte (kernels.quant layout)
-    dt = jnp.int8 if quantized else _cdtype(cfg)
-    t = ops.paged_tokens_per_row(page, store_hd)
-    cache = {
-        "kp": jnp.zeros((n_pages + 1, K, page // t, t * store_hd), dt),
-        "vp": jnp.zeros((n_pages + 1, K, page // t, t * store_hd), dt),
-    }
-    if quantized:
-        cache["kp_scale"] = jnp.zeros((n_pages + 1, K, page), jnp.float32)
-        cache["vp_scale"] = jnp.zeros((n_pages + 1, K, page), jnp.float32)
-    return cache
-
-
-def paged_cache_insert(cache, k_new, v_new, page_table, pos, n_valid,
-                       layer):
-    """Scatter C new tokens' K/V into their rows' pages of one layer.
-
-    ``cache`` is one pool dict (``init_paged_kv_cache``) stacked over
-    layers, ``(n_blocks, n_pages + 1, K, ...)``, as the chunk program
-    carries it; ``layer`` picks the stacked layer written.
-    k_new/v_new: (B, C, K, hd). page_table: (B, max_pages) int32
-    physical page ids (-1 unmapped). pos: (B,) absolute position of each
-    row's first token this step; token i of row b lands at logical
-    position ``pos[b] + i``, i.e. page ``(pos+i) // page``, offset
-    ``(pos+i) % page`` within the row's mapped physical page. Tokens at
-    i >= n_valid[b] (and any position whose page is unmapped) are routed
-    to the layer's trash page. The engine guarantees every valid
-    position's page is mapped before the step runs. Its operations carry
-    the name scope ``kv_insert`` in the compiled program.
-    """
-    with jax.named_scope("kv_insert"):
-        return _paged_insert(cache, k_new, v_new, page_table, pos, n_valid,
-                             layer)
-
-
-def _paged_insert(cache, k_new, v_new, page_table, pos, n_valid, layer):
-    """One scatter per pool leaf into the stack at ``(layer, phys, k,
-    ...)``: a token's ``hd`` values (or its scale) for one KV head. It
-    updates the stack in place, in the layout the stack has: no
-    relayout, and no slice of the layer's pool out of the stack."""
-    B, C, _, hd = k_new.shape
-    P1, K = cache["kp"].shape[1:3]
-    page, store_hd = ops.paged_pool_shape(
-        cache["kp"], cache.get("kp_scale"), hd)
-    npg = page_table.shape[1]
-    logical = (jnp.asarray(pos, jnp.int32).reshape(B, 1)
-               + jnp.arange(C, dtype=jnp.int32)[None, :])      # (B, C)
-    pg, off = logical // page, logical % page
-    phys = jnp.take_along_axis(
-        jnp.asarray(page_table, jnp.int32), jnp.clip(pg, 0, npg - 1), axis=1)
-    ok = (jnp.arange(C, dtype=jnp.int32)[None, :]
-          < jnp.asarray(n_valid, jnp.int32).reshape(B, 1))
-    ok &= (phys >= 0) & (pg < npg)
-    phys = jnp.where(ok, phys, P1 - 1).reshape(B * C, 1)        # trash page
-    off = off.reshape(B * C, 1)
-    kh = jnp.arange(K, dtype=jnp.int32)[None, :]
-    lyr = jnp.asarray(layer, jnp.int32)
-
-    def put(pool, new):  # new (B * C, K, ...)
-        new = new.astype(pool.dtype)
-        t = page // pool.shape[3]  # tokens to a row; 1 for the scales
-        if t == 1:
-            return pool.at[lyr, phys, kh, off].set(new)
-        # the token's lanes of its row
-        w = new.shape[-1]
-        lanes = (off % t * w)[..., None] + jnp.arange(w, dtype=jnp.int32)
-        return pool.at[lyr, phys[..., None], kh[..., None],
-                       (off // t)[..., None], lanes].set(new)
-
-    out = dict(cache)
-    if "kp_scale" in cache:
-        qz = quant.quantize_int4 if store_hd != hd else quant.quantize_int8
-        kq, ks = qz(k_new.reshape(B * C, K, hd))
-        vq, vs = qz(v_new.reshape(B * C, K, hd))
-        out["kp"] = put(cache["kp"], kq)
-        out["vp"] = put(cache["vp"], vq)
-        out["kp_scale"] = put(cache["kp_scale"], ks)
-        out["vp_scale"] = put(cache["vp_scale"], vs)
-    else:
-        _check_insert_dtype(cache["kp"].dtype, k_new.dtype,
-                            "paged_cache_insert")
-        out["kp"] = put(cache["kp"], k_new.reshape(B * C, K, hd))
-        out["vp"] = put(cache["vp"], v_new.reshape(B * C, K, hd))
-    return out
-
-
-def paged_copy_pages(cache, src, dst):
-    """Copy-on-write content copy: pool pages ``src[i] -> dst[i]``.
-
-    ``cache`` is one paged-attention pool dict (``kp``/``vp`` + optional
-    int8 scales), either per-layer ``(n_pages+1, K, page, ...)`` or
-    stacked ``(n_blocks, n_pages+1, K, page, ...)``. The copy runs
-    before the owning slot's next ``paged_cache_insert`` writes into
-    ``dst``, so a shared source page is never mutated.
-    """
-    s = jnp.asarray(src, jnp.int32)
-    d = jnp.asarray(dst, jnp.int32)
-    if cache["kp"].ndim == 5:  # n_blocks-stacked: page axis 1
-        return {k: v.at[:, d].set(v[:, s]) for k, v in cache.items()}
-    return {k: v.at[d].set(v[s]) for k, v in cache.items()}
 
 
 def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
@@ -469,10 +328,11 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
 
     x: (B, C, d) — the chunk program's mixed batch: decode rows feed one
     real token, chunked-prefill rows up to C (``n_valid`` masks the
-    rest). ``cache`` is the pool stacked over layers, as in
-    ``paged_cache_insert``. The new K/V are written into the rows' pages
-    of ``layer`` first, then every query attends causally over exactly
-    its row's occupied pages of that layer (``ops.paged_attention``).
+    rest). ``cache`` is the pool stacked over layers
+    (``kernels/paged_pool.py``). The new K/V are written into the rows'
+    pages of ``layer`` first (``paged_pool.insert``), then every query
+    attends causally over exactly its row's occupied pages of that layer
+    (``ops.paged_attention``).
     Positions beyond ``n_valid`` produce garbage the caller masks at the
     logit gather. ``window`` is the layer's own; rope False for a layer
     without rotary embedding.
@@ -488,15 +348,12 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, page_table,
         if cfg.rope == "mrope":
             posm = jnp.broadcast_to(posm[..., None], (B, C, 3))
     q, k_new = _qk(params, q, k_new, cfg, posm, rope)
-    new_cache = paged_cache_insert(
+    new_cache = paged_pool.insert(
         cache, k_new, v_new, page_table, pos, n_valid, layer)
     with jax.named_scope("attention"):
         out = ops.paged_attention(
-            q, new_cache["kp"], new_cache["vp"], page_table,
-            pos=pos, n_valid=n_valid, window=window,
-            kp_scale=new_cache.get("kp_scale"),
-            vp_scale=new_cache.get("vp_scale"), layer=layer,
-        )
+            q, new_cache, page_table, pos=pos, n_valid=n_valid,
+            layer=layer, window=window)
     return _out_proj(params, x, out, cfg), new_cache
 
 

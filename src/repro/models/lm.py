@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist import constrain, p, retag_tree, split_tree, stack_axes
+from repro.kernels import paged_pool
 from repro.models import layers as L
 
 
@@ -359,19 +360,14 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page: int):
     """Paged decode cache: a physical page pool per pattern position,
     stacked over n_blocks. Attention-only stacks — recurrent mixers
     carry per-slot state, not KV, and stay on the slab layout."""
-    entries = []
     for spec in cfg.block_pattern:
         if spec.mixer != "attn":
             raise ValueError(
                 f"paged KV cache requires an attention-only stack; "
                 f"{cfg.name} has a {spec.mixer!r} mixer")
-        e = L.init_paged_kv_cache(cfg, n_pages, page)
-        entries.append(
-            jax.tree_util.tree_map(
-                lambda a: jnp.broadcast_to(a, (cfg.n_blocks,) + a.shape), e
-            )
-        )
-    return tuple(entries)
+    return tuple(paged_pool.init(cfg.n_blocks, n_pages, page, cfg.n_kv_heads,
+                                 cfg.head_dim, cfg.kv_cache_dtype, cfg.dtype)
+                 for _ in cfg.block_pattern)
 
 
 def decode_chunk(params, cfg: ModelConfig, tokens, cache, page_table, pos,

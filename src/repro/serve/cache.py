@@ -16,16 +16,17 @@ budget for admission, compaction (``defrag``) — and, since PR 6,
 (cross-request prefix sharing, ``serve.prefix.PrefixIndex``), may be
 pinned by the prefix index with no slot referencing it (``cache``/
 ``uncache``), and is copy-on-written (``cow``) before a slot writes
-into a page another holder can still see. The device side is
-``models.layers.init_paged_kv_cache`` / ``paged_cache_insert`` /
-``kernels.ops.paged_attention``, reached through the same
-init/write/read/invalidate-shaped surface the engine always used: init
-(``ModelAPI.init_paged_cache``), write (the chunk program's page
-scatter), read (`table_row` feeding the gather), invalidate
-(``free_slot`` — dropping the mapping *is* the invalidation; no mask
-pass needed, which is the point of paging). Memory no longer scales as
-``max_batch x max_len`` but as actual tokens held, the serving analogue
-of the paper's partition-what-no-longer-fits story (§3).
+into a page another holder can still see. The device side, and the one
+home of the pool's layout, is ``repro.kernels.paged_pool``: it builds
+the pool (``ModelAPI.init_paged_cache`` calls it), writes it (the chunk
+program's ``insert``), hands the kernel one layer's view (read, with
+``table_row`` feeding the page table), and copies and permutes pages
+(``copy_pages`` and ``apply_defrag`` below are thin entry points over
+it). Invalidation is ``free_slot``: dropping the mapping *is* the
+invalidation; no mask pass needed, which is the point of paging. Memory
+no longer scales as ``max_batch x max_len`` but as actual tokens held,
+the serving analogue of the paper's partition-what-no-longer-fits story
+(§3).
 """
 from __future__ import annotations
 
@@ -34,6 +35,8 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels import paged_pool
 
 
 def init_slab(api, max_batch: int, max_len: int, window=None):
@@ -276,51 +279,19 @@ class PagePool:
 
 
 def apply_defrag(cache, perm):
-    """Gather every paged pool leaf into the post-``defrag`` page order.
-
-    Leaves are ``(n_blocks, n_pages + 1, K, page, ...)``; ``perm`` comes
-    from :meth:`PagePool.defrag`. Dense entries (enc-dec ``cross``
-    slabs, recurrent states) are left untouched.
-    """
-    permj = jnp.asarray(perm, jnp.int32)
-
-    def rec(node):
-        if isinstance(node, dict):
-            if "kp" in node:
-                return {k: jnp.take(v, permj, axis=1)
-                        for k, v in node.items()}
-            return {k: (v if k == "cross" else rec(v))
-                    for k, v in node.items()}
-        if isinstance(node, (tuple, list)):
-            return tuple(rec(x) for x in node)
-        return node
-
-    return rec(cache)
+    """Every paged pool of ``cache`` in the post-``defrag`` page order
+    (``perm`` from :meth:`PagePool.defrag`); other entries are kept."""
+    perm = jnp.asarray(perm, jnp.int32)  # one transfer for every pool
+    return paged_pool.map_pools(cache, lambda p: paged_pool.permute(p, perm))
 
 
 def copy_pages(cache, src: List[int], dst: List[int]):
     """Duplicate physical pages ``src[i] -> dst[i]`` in every paged pool
-    leaf (the device half of :meth:`PagePool.cow`).
-
-    Dense entries (enc-dec ``cross`` slabs) are untouched; the per-layer
-    copy is ``models.layers.paged_copy_pages`` so bf16 and int8 pools
-    (K/V plus dequant scales) share one path.
-    """
+    of ``cache`` (the device half of :meth:`PagePool.cow`)."""
     if not src:
         return cache
-    from repro.models.layers import paged_copy_pages
-
-    def rec(node):
-        if isinstance(node, dict):
-            if "kp" in node:
-                return paged_copy_pages(node, src, dst)
-            return {k: (v if k == "cross" else rec(v))
-                    for k, v in node.items()}
-        if isinstance(node, (tuple, list)):
-            return tuple(rec(x) for x in node)
-        return node
-
-    return rec(cache)
+    return paged_pool.map_pools(
+        cache, lambda p: paged_pool.copy_pages(p, src, dst))
 
 
 def invalidate_beyond(cache, true_len):

@@ -205,8 +205,9 @@ def check_kernels_per_shard():
             pos = jnp.asarray([0, 9, 13, 5], jnp.int32)
             qc = q[:, :C]
             with MESH, use_rules(rules):
-                got = jax.jit(lambda *a: ops.paged_attention(
-                    *a, pos=pos, n_valid=nv))(qc, kp, vp, pt)
+                got = jax.jit(lambda q, kp, vp, pt: ops.paged_attention(
+                    q, {"kp": kp[None], "vp": vp[None]}, pt, pos=pos,
+                    n_valid=nv, layer=0))(qc, kp, vp, pt)
             want = kref.paged_attention(qc, kp, vp, pt, pos=pos, n_valid=nv)
             for b in range(B):
                 n = int(nv[b])

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_pool, ref
 from repro.models import layers as L
 
 KEY = jax.random.PRNGKey(0)
@@ -99,8 +99,25 @@ def test_cache_from_prefill_matches_inserts():
 
 
 # --------------------------------------------------------------------------- #
-# Paged pool insert (serving; see repro.serve.cache.PagePool).
+# Paged pool insert, copy and permute (serving; see
+# repro.serve.cache.PagePool for the host side).
 # --------------------------------------------------------------------------- #
+def _pool(kv_dtype, n_layers, n_pages, page):
+    """A pool as ``paged_pool.init`` lays it out, every row filled with
+    non-zero values so that a stray write shows."""
+    cfg = _cfg(kv_dtype)
+    pool = paged_pool.init(n_layers, n_pages, page, cfg.n_kv_heads,
+                           cfg.head_dim, kv_dtype, cfg.dtype)
+    keys = iter(jax.random.split(KEY, len(pool)))
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jax.random.randint(next(keys), a.shape, 1, 100, a.dtype)
+        return jax.random.uniform(next(keys), a.shape, a.dtype, 0.5, 1.5)
+
+    return cfg, {name: fill(a) for name, a in pool.items()}
+
+
 @pytest.mark.parametrize("n_blocks,layer", [(1, 0), (3, 2)],
                          ids=["one-layer", "stacked"])
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "int4"])
@@ -115,36 +132,25 @@ def test_paged_cache_insert_roundtrip(kv_dtype, n_blocks, layer):
     several tokens to a row here (head_dim 16, int4-packed 8)."""
     from repro.kernels import quant
 
-    cfg = _cfg(kv_dtype)
-    K, hd = cfg.n_kv_heads, cfg.head_dim
     page, n_pages = 4, 6
-    pool = L.init_paged_kv_cache(cfg, n_pages, page)
+    cfg, cache = _pool(kv_dtype, n_blocks, n_pages, page)
+    K, hd = cfg.n_kv_heads, cfg.head_dim
     store_hd = hd // 2 if kv_dtype == "int4" else hd
-    t = ops.paged_tokens_per_row(page, store_hd)
+    t = paged_pool.tokens_per_row(page, store_hd)
     assert t > 1
-    assert pool["kp"].shape == (n_pages + 1, K, page // t,
-                                t * store_hd)  # + trash
-    assert pool["kp"].dtype == (jnp.int8 if kv_dtype != "bfloat16"
-                                else jnp.bfloat16)
-    # every row starts non-zero, so that a stray write shows
-    keys = iter(jax.random.split(KEY, 8))
-    lead = (n_blocks,)
-
-    def fill(a):
-        if jnp.issubdtype(a.dtype, jnp.integer):
-            return jax.random.randint(next(keys), lead + a.shape, 1, 100,
-                                      a.dtype)
-        return jax.random.uniform(next(keys), lead + a.shape, a.dtype,
-                                  0.5, 1.5)
-
-    cache = {name: fill(a) for name, a in pool.items()}
+    assert cache["kp"].shape == (n_blocks, n_pages + 1, K, page // t,
+                                 t * store_hd)  # + trash
+    assert cache["kp"].dtype == (jnp.int8 if kv_dtype != "bfloat16"
+                                 else jnp.bfloat16)
+    assert paged_pool.geometry(cache, hd) == (
+        page, store_hd, t, "" if kv_dtype == "bfloat16" else kv_dtype)
     pt = jnp.asarray([[2, 5, -1], [4, -1, -1]], jnp.int32)
-    k = jax.random.normal(next(keys), (2, 3, K, hd)) * 3.0
+    k = jax.random.normal(jax.random.PRNGKey(1), (2, 3, K, hd)) * 3.0
     # row 0 writes positions 3..5 (page 0 tail + page 1 head); row 1
     # writes position 1 only (n_valid 1), its masked tokens 1 and 2
     # (positions 2, 3) go to the trash page
-    out = L.paged_cache_insert(cache, k, k, pt, jnp.asarray([3, 1], jnp.int32),
-                               jnp.asarray([3, 1], jnp.int32), layer)
+    out = paged_pool.insert(cache, k, k, pt, jnp.asarray([3, 1], jnp.int32),
+                            jnp.asarray([3, 1], jnp.int32), layer)
     writes = {(2, 3): (0, 0), (5, 0): (0, 1), (5, 1): (0, 2), (4, 1): (1, 0),
               (n_pages, 2): (1, 1), (n_pages, 3): (1, 2)}
     for name in cache:
@@ -170,6 +176,38 @@ def test_paged_cache_insert_roundtrip(kv_dtype, n_blocks, layer):
             else:
                 step = scale[phys, :, off][..., None]
                 assert float((err - step).max()) < 1e-5
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "int4"])
+def test_paged_pool_copy_and_permute_move_whole_pages(kv_dtype):
+    """On a three-layer stack whose rows hold several tokens (head_dim
+    16, int4-packed 8): ``copy_pages`` writes each source page over its
+    destination and ``permute`` puts every page where the permutation
+    says, in every layer and every leaf, scales included; every other
+    page stays bit-identical."""
+    n_layers, n_pages, page = 3, 6, 4
+    _, pool = _pool(kv_dtype, n_layers, n_pages, page)
+    assert paged_pool.geometry(pool, 16)[2] > 1
+    assert set(pool) == ({"kp", "vp"} if kv_dtype == "bfloat16" else
+                         {"kp", "vp", "kp_scale", "vp_scale"})
+    before = {name: np.asarray(a) for name, a in pool.items()}
+
+    copied = paged_pool.copy_pages(pool, [1, 4], [3, 0])
+    moves = {3: 1, 0: 4}  # destination page <- source page
+    for name, a in copied.items():
+        a = np.asarray(a)
+        assert a.dtype == before[name].dtype
+        for p in range(n_pages + 1):
+            np.testing.assert_array_equal(
+                a[:, p], before[name][:, moves.get(p, p)], err_msg=name)
+
+    perm = np.asarray([5, 0, 3, 1, 2, 4, n_pages], np.int32)
+    moved = paged_pool.permute(pool, perm)
+    for name, a in moved.items():
+        a = np.asarray(a)
+        assert a.shape == before[name].shape
+        np.testing.assert_array_equal(a, before[name][:, perm],
+                                      err_msg=name)
 
 
 def test_int4_slab_cache_rejected():
@@ -202,15 +240,13 @@ def test_insert_refuses_silent_upcast_into_integer_pool():
     L.cache_insert(cache, knew, knew, 0)
 
     # paged: same contract, on a stack of one layer
-    pcache = {k: v[None] for k, v in L.init_paged_kv_cache(cfg, 2, 4).items()}
+    pcache = paged_pool.init(1, 2, 4, K, hd, cfg.kv_cache_dtype, cfg.dtype)
     pbare = {k: v for k, v in pcache.items()
              if k not in ("kp_scale", "vp_scale")}
     pt = jnp.asarray([[0, 1]], jnp.int32)
     kc = jnp.ones((1, 2, K, hd))
     with pytest.raises(TypeError, match="quantization scales"):
-        L.paged_cache_insert(pbare, kc, kc, pt,
-                             jnp.asarray([0], jnp.int32),
-                             jnp.asarray([2], jnp.int32), 0)
-    L.paged_cache_insert(pcache, kc, kc, pt,
-                         jnp.asarray([0], jnp.int32),
-                         jnp.asarray([2], jnp.int32), 0)
+        paged_pool.insert(pbare, kc, kc, pt, jnp.asarray([0], jnp.int32),
+                          jnp.asarray([2], jnp.int32), 0)
+    paged_pool.insert(pcache, kc, kc, pt, jnp.asarray([0], jnp.int32),
+                      jnp.asarray([2], jnp.int32), 0)

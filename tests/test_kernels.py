@@ -9,7 +9,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import lstm_cell as lk
 from repro.kernels import lars as lkr
 from repro.kernels import mamba as mk
-from repro.kernels import ops
+from repro.kernels import ops, paged_pool
 
 KEY = jax.random.PRNGKey(0)
 
@@ -257,6 +257,13 @@ def _paged_kernel_case(case, seed, dtype):
     return nvs, (q, kp, vp, pt, pos, nv)
 
 
+def _stack_of_one(kp, vp, kp_scale=None, vp_scale=None):
+    """One layer's ``(P, K, page, hd)`` pool (and scales) as the stack of
+    one layer ``ops.paged_attention`` reads at ``layer=0``."""
+    leaves = dict(kp=kp, vp=vp, kp_scale=kp_scale, vp_scale=vp_scale)
+    return {k: v[None] for k, v in leaves.items() if v is not None}
+
+
 def _paged_params(values):
     """(case, window, value) parameters for each (id, value) of
     ``values``; the "small" ones keep the ids they had as the only case."""
@@ -294,7 +301,8 @@ def test_paged_attention_ops_fallback_vs_ref():
     q, kp, vp, pt, pos, nv = _paged_case(
         5, 2, 3, 4, 1, 16, 2, 10, 6, lens, nvs, jnp.float32)
     want = ref.paged_attention(q, kp, vp, pt, pos=pos, n_valid=nv)
-    got = ops.paged_attention(q, kp, vp, pt, pos=pos, n_valid=nv)
+    got = ops.paged_attention(q, _stack_of_one(kp, vp), pt, pos=pos,
+                              n_valid=nv, layer=0)
     for b, n in enumerate(nvs):
         np.testing.assert_allclose(
             np.asarray(got[b, :n]), np.asarray(want[b, :n]),
@@ -331,8 +339,8 @@ def test_paged_attention_matches_dense_decode():
             kp = kp.at[phys, :, t % page].set(k[b, t])
             vp = vp.at[phys, :, t % page].set(v[b, t])
     got = ops.paged_attention(
-        q, kp, vp, pt, pos=jnp.full((B,), S, jnp.int32),
-        n_valid=jnp.ones((B,), jnp.int32))
+        q, _stack_of_one(kp, vp), pt, pos=jnp.full((B,), S, jnp.int32),
+        n_valid=jnp.ones((B,), jnp.int32), layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -375,7 +383,7 @@ def test_quantize_bounded_error(qz, lim):
 def test_paged_attention_reads_one_layer_of_a_stack(pool, D, backend,
                                                     monkeypatch):
     """``ops.paged_attention(..., layer=l)`` on the chunk program's pools
-    stacked over three layers, stored as ``init_paged_kv_cache`` stores
+    stacked over three layers, stored as ``paged_pool.init`` stores
     them (two tokens to a row where a token is 64 lanes wide), reads layer
     1 alone: equal to the oracle on that layer's own pool, through the
     kernel (interpret mode; the stack as one pool at 128 lanes, the
@@ -400,11 +408,11 @@ def test_paged_attention_reads_one_layer_of_a_stack(pool, D, backend,
         q, kps[layer], vps[layer], pt, pos=pos, n_valid=nv,
         **{k: v[layer] for k, v in scales.items()})
     hd = kps.shape[-1]
-    t = ops.paged_tokens_per_row(page, hd)
+    t = paged_pool.tokens_per_row(page, hd)
     assert t == (2 if hd == 64 else 1)
     rows = lambda a: a.reshape(a.shape[:3] + (page // t, t * hd))
-    got = ops.paged_attention(q, rows(kps), rows(vps), pt, pos=pos,
-                              n_valid=nv, layer=jnp.int32(layer), **scales)
+    got = ops.paged_attention(q, dict(kp=rows(kps), vp=rows(vps), **scales),
+                              pt, pos=pos, n_valid=nv, layer=jnp.int32(layer))
     tol = (_tol(jnp.bfloat16) if pool == "bfloat16"
            else dict(rtol=2e-5, atol=2e-5))
     for b, m in enumerate(nvs):
@@ -455,8 +463,8 @@ def test_paged_attention_quantized_ops_fallback_vs_ref(qdtype):
     kpq, vpq, ks, vs = _quantize_pool(kp, vp, qz)
     want = ref.paged_attention(q, kpq, vpq, pt, pos=pos, n_valid=nv,
                                kp_scale=ks, vp_scale=vs)
-    got = ops.paged_attention(q, kpq, vpq, pt, pos=pos, n_valid=nv,
-                              kp_scale=ks, vp_scale=vs)
+    got = ops.paged_attention(q, _stack_of_one(kpq, vpq, ks, vs), pt,
+                              pos=pos, n_valid=nv, layer=0)
     for b, n in enumerate(nvs):
         np.testing.assert_allclose(
             np.asarray(got[b, :n]), np.asarray(want[b, :n]),

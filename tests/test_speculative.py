@@ -112,13 +112,19 @@ def test_spec_decode_token_identical_and_one_program():
 def test_spec_decode_identity_under_preemption_and_prefix_cache():
     """Pool pressure (preemptions force re-prefill of accepted tokens)
     and the cross-request prefix cache both stay invisible to
-    speculative greedy outputs."""
+    speculative greedy outputs. The prompts repeat 4-grams, so the
+    n-gram drafter proposes; the first two requests send one whole
+    prompt, so the second matches every page of it in the prefix cache
+    and copy-on-writes its last page."""
     cfg, params, mesh, rules = _setup("gemma-7b", "tp2d")
 
     def mk():
-        return synthetic_requests(
-            cfg, n=6, tokens=6, prompt_len=16, scenario="server", seed=9,
-            shared_prefix_len=12, n_templates=2)
+        rng = np.random.RandomState(9)
+        a, b, c = (rng.randint(0, cfg.vocab, size=4).tolist()
+                   for _ in range(3))
+        prompts = [a * 4, a * 4, b * 3 + c, c * 3, b * 4, c * 2 + b]
+        return [Request(prompt=p, max_new_tokens=10, arrival_step=t)
+                for p, t in zip(prompts, (0, 4, 5, 6, 7, 12))]
 
     base = dict(max_batch=3, max_len=32, kv_layout="paged", page_size=4,
                 prefill_chunk=6, n_pages=12, prefix_cache=True)
@@ -135,6 +141,7 @@ def test_spec_decode_identity_under_preemption_and_prefix_cache():
         "12-page pool should have preempted; widen the workload if not")
     assert report.prefix_hit_rate is not None
     assert report.draft_tokens > 0
+    assert report.cow_copies > 0
 
 
 @pytest.mark.slow
