@@ -275,8 +275,11 @@ def _run_fleet(spec: RunSpec, cfg, mesh, rules, params, scfg,
     fcfg = FleetConfig(routing=f.routing,
                        heartbeat_timeout=f.heartbeat_timeout)
     with mesh, use_rules(rules):
-        engines = [Engine(cfg, params, rules, scfg)
-                   for _ in range(f.n_replicas)]
+        # The first engine makes the compute-dtype copy of the weights;
+        # the others are handed it, so the fleet holds one copy.
+        engines = [Engine(cfg, params, rules, scfg)]
+        engines += [Engine(cfg, engines[0].params, rules, scfg)
+                    for _ in range(f.n_replicas - 1)]
         if s.warmup:
             from repro.serve.scenarios import scenario_driver
             for e in engines:

@@ -72,6 +72,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.dist import Rules, use_rules
+from repro.optim.precision import serving_copy
 from repro.serve import cache as slab_ops
 from repro.serve import slo
 from repro.serve.metrics import ServeReport, StepTrace
@@ -159,7 +160,6 @@ class Engine:
             # config's kv_cache_dtype for cache construction + inserts.
             cfg = dataclasses.replace(cfg, kv_cache_dtype=self.scfg.kv_dtype)
         self.cfg = cfg
-        self.params = params
         self.rules = rules
         self.api = ModelAPI(cfg)
         # Recurrent mixers carry prompt state -> exact-length prefill.
@@ -214,6 +214,10 @@ class Engine:
         self._windows = [s.window for s in cfg.block_pattern
                          if s.mixer == "attn" and s.window] * cfg.n_blocks
 
+        # Every program the engine runs, with abstract arguments at its
+        # compile shapes: what they do with each weight decides which
+        # weights are served from a compute-dtype copy.
+        B, sds, i32 = self.scfg.max_batch, jax.ShapeDtypeStruct, jnp.int32
         if layout == "paged":
             # With a drafter the one chunk program returns the head over
             # all C positions (verify needs every draft's logits) — a
@@ -221,9 +225,15 @@ class Engine:
             # is donated the KV pool and updates it in place: every
             # holder of ``self._cache`` rebinds it to the program's
             # output before the next step.
-            self._chunk_jit = jax.jit(make_serve_chunk_step(
-                cfg, rules, full_logits=self._drafter is not None),
-                donate_argnums=(2,))
+            chunk = make_serve_chunk_step(
+                cfg, rules, full_logits=self._drafter is not None)
+            self._chunk_jit = jax.jit(chunk, donate_argnums=(2,))
+            cache = jax.eval_shape(lambda: self.api.init_paged_cache(
+                B, self.scfg.pool_pages, self.scfg.page_size))
+            programs = [(chunk, (
+                sds((B, self.scfg.prefill_chunk), i32), cache,
+                sds((B, self.scfg.max_pages), i32), sds((B,), i32),
+                sds((B,), i32)))]
             if cfg.is_encdec:
                 api = self.api
 
@@ -233,6 +243,9 @@ class Engine:
                     return slab_ops.write_slot(cross, kv, slot)
 
                 self._encode_jit = jax.jit(encode_insert)
+                programs.append((encode_insert, (
+                    sds((1, cfg.enc_source_len, cfg.d_model), jnp.float32),
+                    cache["cross"], sds((), i32))))
         else:
             prefill_step = make_serve_prefill_step(
                 cfg, rules, cache_len=self.scfg.max_len)
@@ -245,6 +258,27 @@ class Engine:
 
             self._prefill_jit = jax.jit(prefill_insert)
             self._decode_jit = jax.jit(decode_step)
+            slab = jax.eval_shape(lambda: slab_ops.init_slab(
+                self.api, B, self.scfg.max_len))
+            n_media = (cfg.enc_source_len if cfg.is_encdec else
+                       cfg.n_media_tokens
+                       if cfg.frontend == "vision_patches" else 0)
+            batch = {"tokens": sds((1, self.scfg.prefill_len), i32)}
+            if n_media:
+                batch["media"] = sds((1, n_media, cfg.d_model), jnp.float32)
+            programs = [
+                (prefill_insert, (batch, sds((1,), i32), sds((1,), i32),
+                                  slab, sds((), i32))),
+                (decode_step, (sds((B, 1), i32), slab, sds((B,), i32)))]
+        # Served from a copy in the compute dtype, made once: the weights
+        # never change, so the programs no longer cast them every step.
+        self.params = serving_copy(params, programs, cfg.dtype)
+        dt = jnp.dtype(cfg.dtype)
+        leaves = jax.tree_util.tree_leaves(self.params)
+        in_copy = sum(w.size for w in leaves
+                      if w.dtype == dt and dt != jnp.float32)
+        self._cast_share = 100.0 * in_copy / max(
+            1, sum(w.size for w in leaves))
         self._key = jax.random.PRNGKey(self.scfg.seed)
         self.reset()
 
@@ -472,6 +506,7 @@ class Engine:
                 self._draft_accepted / max(self._draft_total, 1)
                 if self._drafter is not None else None),
             draft_tokens=self._draft_total,
+            weights_cast_share=self._cast_share,
         )
         self.reset()
         return report

@@ -78,6 +78,9 @@ class ServeReport:
     # -- speculative decoding (paged engine, serve.speculative) --------- #
     spec_accept_rate: Optional[float] = None  # accepted / proposed drafts
     draft_tokens: int = 0        # draft tokens proposed across the run
+    # -- weights (optim.precision.serving_copy) ------------------------ #
+    weights_cast_share: Optional[float] = None  # % of parameter elements
+                                 # served from the compute-dtype copy
 
     # ------------------------------------------------------------------ #
     @property

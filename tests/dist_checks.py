@@ -217,6 +217,43 @@ def check_kernels_per_shard():
     print("OK kernels_per_shard")
 
 
+def check_serving_copy_keeps_sharding():
+    """The engine's compute-dtype copy of its weights keeps each
+    master's sharding under ``wus`` and ``tp2d`` on a 2x2 mesh, and
+    serves the tokens the same programs give fed the masters."""
+    from jax.sharding import NamedSharding
+    from repro.configs import get_config
+    from repro.dist import Rules, param_specs, split_tree, use_rules
+    from repro.launch.mesh import make_test_mesh
+    from repro.serve import Engine, ServeConfig, run_offline
+    from repro.serve.engine import synthetic_requests
+    from repro.train.steps import ModelAPI
+
+    cfg = get_config("yi-9b").reduced()
+    mesh = make_test_mesh(2, 2)
+    scfg = ServeConfig(max_batch=4, max_len=24, prefill_len=12,
+                       page_size=4, prefill_chunk=4)
+    for mode in ("wus", "tp2d"):
+        rules = Rules(mesh, mode)
+        with mesh, use_rules(rules):
+            master, axes = split_tree(ModelAPI(cfg).init(cfg, KEY))
+            master = jax.tree_util.tree_map(
+                lambda w, s: jax.device_put(w, NamedSharding(mesh, s)),
+                master, param_specs(axes, master, rules))
+            eng = Engine(cfg, master, rules, scfg)
+            ref = Engine(cfg, master, rules, scfg)
+            ref.params = master
+            got = [run_offline(e, synthetic_requests(
+                cfg, n=3, tokens=4, prompt_len=10, seed=1)).requests
+                for e in (eng, ref)]
+        pairs = list(zip(jax.tree_util.tree_leaves(master),
+                         jax.tree_util.tree_leaves(eng.params)))
+        assert all(c.sharding == m.sharding for m, c in pairs), mode
+        assert any(c.dtype == jnp.bfloat16 for _, c in pairs), mode
+        assert [r.tokens for r in got[0]] == [r.tokens for r in got[1]]
+    print("OK serving_copy_sharding")
+
+
 if __name__ == "__main__":
     check_gradsum_2d_equals_sum()
     check_flatten_roundtrip()
@@ -229,4 +266,5 @@ if __name__ == "__main__":
     check_sharded_trainer_matches_single_device()
     check_graph_partitioning_equivalence()
     check_kernels_per_shard()
+    check_serving_copy_keeps_sharding()
     print("ALL_DIST_CHECKS_PASSED")
